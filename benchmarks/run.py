@@ -9,10 +9,15 @@ scripts/check.sh's ``kernels`` target enforces in CI.
 
 ``--profile DIR`` wraps the selected figures in ``jax.profiler.trace``:
 one TensorBoard-loadable trace (device dispatches + host annotations)
-lands in DIR — see DESIGN.md §11."""
+lands in DIR — see DESIGN.md §11.
+
+``--selftest`` runs ``scripts/check.sh smoke`` (pytest on the CPU) before
+this process imports JAX: a parent holding the chip would starve the
+child."""
 
 import argparse
 import contextlib
+import importlib
 import json
 import os
 import subprocess
@@ -27,22 +32,18 @@ _SRC = os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
 if _SRC not in sys.path:
     sys.path.insert(0, _SRC)
 
-from benchmarks import (beyond_fused_batch, fig3_spann_scaling, fig4_combos,
-                        fig5_rerank, fig9_throughput_latency,
-                        fig10_accuracy_levels, fig11_thread_scaling,
-                        fig12_ablation, kernels_bench, tab2_tab3_cost)
-
+# figure name -> benchmarks module, imported only after the selftest
 ALL = {
-    "fig3": fig3_spann_scaling,
-    "fig4": fig4_combos,
-    "fig5": fig5_rerank,
-    "fig9": fig9_throughput_latency,
-    "fig10": fig10_accuracy_levels,
-    "fig11": fig11_thread_scaling,
-    "fig12": fig12_ablation,
-    "tab2_tab3": tab2_tab3_cost,
-    "kernels": kernels_bench,
-    "beyond": beyond_fused_batch,
+    "fig3": "fig3_spann_scaling",
+    "fig4": "fig4_combos",
+    "fig5": "fig5_rerank",
+    "fig9": "fig9_throughput_latency",
+    "fig10": "fig10_accuracy_levels",
+    "fig11": "fig11_thread_scaling",
+    "fig12": "fig12_ablation",
+    "tab2_tab3": "tab2_tab3_cost",
+    "kernels": "kernels_bench",
+    "beyond": "beyond_fused_batch",
 }
 
 
@@ -102,7 +103,7 @@ def main() -> None:
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
         rc = subprocess.run(
             ["bash", os.path.join(root, "scripts", "check.sh"), "smoke"],
-            cwd=root).returncode
+            cwd=root, env={**os.environ, "JAX_PLATFORMS": "cpu"}).returncode
         if rc != 0:
             print(f"# selftest FAILED (rc={rc})", file=sys.stderr)
             sys.exit(rc)
@@ -111,6 +112,8 @@ def main() -> None:
             print("name,us_per_call,derived")
             print("selftest,0.0,scripts/check.sh smoke passed")
             return
+    from repro.launch.compile_cache import enable_compile_cache
+    enable_compile_cache()
     names = args.only or list(ALL)
     root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
     factor = float(os.environ.get("BENCH_REGRESSION_FACTOR", "1.6"))
@@ -127,7 +130,8 @@ def main() -> None:
         for name in names:
             t0 = time.time()
             try:
-                rows = ALL[name].run()
+                rows = importlib.import_module(
+                    f"benchmarks.{ALL[name]}").run()
                 for r in rows:
                     derived = str(r["derived"]).replace(",", ";")
                     print(f"{r['name']},{r['us_per_call']:.1f},{derived}")

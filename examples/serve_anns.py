@@ -27,9 +27,11 @@ from repro.configs.anns_datasets import SIFT_SMALL
 from repro.core.engine import FusionANNSIndex, ground_truth, recall_at_k
 from repro.core.perf_model import DeviceModel, QueryDemand, sweep_threads
 from repro.data.synthetic import clustered_vectors
+from repro.launch.compile_cache import enable_compile_cache
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--n", type=int, default=30_000)
     ap.add_argument("--dim", type=int, default=96)

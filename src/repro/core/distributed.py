@@ -1,10 +1,14 @@
 """Distributed FusionANNS scan: PQ codes row-sharded across every device's
 HBM (the paper's "pinned in GPU HBM" tier, scaled to a pod — DESIGN.md §2).
 
-Per query batch: each device ADC-scans its code shard (Pallas kernel on TPU,
-jnp oracle under interpret/CPU), takes a *local* top-n, and one small
-``all_gather`` of (dist, global-id) pairs merges shards — vector contents
-never cross the interconnect, exactly the paper's ID-only invariant."""
+Per query batch: each device ADC-scans its code shard, takes a *local*
+top-n, and one small ``all_gather`` of (dist, global-id) pairs merges
+shards — vector contents never cross the interconnect, exactly the paper's
+ID-only invariant.
+
+The scan is the XLA form on every backend: the TPU compiler refuses the
+Pallas ADC kernels (``kernels/pq_adc``), and on the CPU they only run in
+the interpreter the parity tests use."""
 
 from __future__ import annotations
 
@@ -17,7 +21,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.kernels.pq_adc.ref import pq_adc_ref
 from repro.models.layers import ShardCtx
-from repro.sharding.spec import shard_map_compat as _shard_map
+from repro.sharding.spec import shard_map
 
 
 def window_scan_ready(*arrays) -> bool:
@@ -53,6 +57,15 @@ def replicate_to_mesh(x: jax.Array, ctx: ShardCtx) -> jax.Array:
         return x
     spec = P(*((None,) * x.ndim))
     return jax.device_put(x, NamedSharding(ctx.mesh, spec))
+
+
+@functools.lru_cache(maxsize=64)
+def _sharded_program(body, mesh: Mesh, in_specs, out_specs, **static):
+    """``body`` as one jitted ``shard_map`` program per (mesh, static
+    arguments).  Called outside ``jit``, ``shard_map`` runs its body op by
+    op and compiles dozens of small programs on every call."""
+    return jax.jit(shard_map(functools.partial(body, **static), mesh=mesh,
+                             in_specs=in_specs, out_specs=out_specs))
 
 
 def _gather_merge_batched(vals, gids, axes, n_shards: int, tk_out: int):
@@ -95,7 +108,7 @@ def sharded_adc_topn(codes: jax.Array, lut: jax.Array, top_n: int,
         n_shards *= ctx.mesh.shape[a]
     body = functools.partial(_local_scan_topn, top_n=top_n, axes=axes_t,
                              n_shards=n_shards)
-    return _shard_map(
+    return shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(axes, None), P(None, None)),
         out_specs=(P(), P()),
@@ -170,7 +183,7 @@ def sharded_adc_topn_batch(codes: jax.Array, luts: jax.Array, top_n: int,
                                         n_shards)
             return jax.lax.map(one, luts_l)
 
-    return _shard_map(
+    return shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(axes, None), P(None, None, None)),
         out_specs=(P(None, None), P(None, None)),
@@ -178,14 +191,14 @@ def sharded_adc_topn_batch(codes: jax.Array, luts: jax.Array, top_n: int,
 
 
 def _local_scan_topn_window(codes, luts, mask, top_n: int, axes,
-                            n_shards: int, use_kernel: bool):
+                            n_shards: int):
     """Per-shard body of the executor's windowed scan: score this shard's
     candidate rows for all B queries, mask non-members / padding to +inf,
     take a per-shard per-query top-n, and all_gather only the (distance,
     global-position) pairs before the global merge."""
-    from repro.kernels.pq_adc.ops import pq_adc_batch
+    from repro.kernels.pq_adc.ref import pq_adc_batch_ref
     n_loc = codes.shape[0]
-    dist = pq_adc_batch(codes, luts, use_kernel=use_kernel)   # (B, n_loc)
+    dist = pq_adc_batch_ref(codes, luts)                      # (B, n_loc)
     dist = jnp.where(mask, dist, jnp.inf)
     tk = min(top_n, n_loc)
     neg, idx = jax.lax.top_k(-dist, tk)
@@ -196,8 +209,7 @@ def _local_scan_topn_window(codes, luts, mask, top_n: int, axes,
 
 
 def sharded_adc_topn_window(codes: jax.Array, luts: jax.Array,
-                            mask: jax.Array, top_n: int, ctx: ShardCtx, *,
-                            use_kernel: bool = False
+                            mask: jax.Array, top_n: int, ctx: ShardCtx
                             ) -> Tuple[jax.Array, jax.Array]:
     """Executor stage ⑤: candidate-bucket scan with per-query membership.
 
@@ -205,30 +217,26 @@ def sharded_adc_topn_window(codes: jax.Array, luts: jax.Array,
     and mask (B, N) bool (True where row N is one of query B's candidates;
     padding rows all-False) -> (dists (B, tk), bucket positions (B, tk))
     replicated, tk = min(top_n, N).  Masked-out slots surface as +inf.
-    Single-device (``ctx.mesh is None``) falls back to the fused kernel
-    wrapper — identical results, so sharded == unsharded is testable."""
+    Single-device (``ctx.mesh is None``) runs the same scan as one
+    program — identical results, so sharded == unsharded is testable."""
     if ctx.mesh is None:
         from repro.kernels.pq_adc.ops import pq_adc_topk_batch
         return pq_adc_topk_batch(codes, luts, top_n, mask=mask,
-                                 use_kernel=use_kernel)
+                                 use_kernel=False)
     axes = ctx.rules.corpus
     axes_t = (axes,) if isinstance(axes, str) else tuple(axes)
     n_shards = 1
     for a in axes_t:
         n_shards *= ctx.mesh.shape[a]
-    body = functools.partial(_local_scan_topn_window, top_n=top_n,
-                             axes=axes_t, n_shards=n_shards,
-                             use_kernel=use_kernel)
-    return _shard_map(
-        body, mesh=ctx.mesh,
-        in_specs=(P(axes, None), P(None, None, None), P(None, axes)),
-        out_specs=(P(None, None), P(None, None)),
-    )(codes, luts, mask)
+    return _sharded_program(
+        _local_scan_topn_window, ctx.mesh,
+        (P(axes, None), P(None, None, None), P(None, axes)),
+        (P(None, None), P(None, None)),
+        top_n=top_n, axes=axes_t, n_shards=n_shards)(codes, luts, mask)
 
 
 def _local_scan_topn_rows(codes, queries, codebooks, rows, top_n: int,
-                          axes, n_shards: int, use_kernel: bool,
-                          lut_int8: bool):
+                          axes, n_shards: int, lut_int8: bool):
     """Per-shard body of the FUSED windowed scan: each query scans its own
     candidate-row list.  ``rows`` holds GLOBAL row ids (replicated); this
     shard scores only the ids that land in its local range, surfaces the
@@ -242,7 +250,7 @@ def _local_scan_topn_rows(codes, queries, codebooks, rows, top_n: int,
     # keep ascending-id order inside the shard: misses -> -1 pads
     local = jnp.where(mine, local, -1)
     vals, lids = pq_adc_fused_topk(codes, queries, codebooks, local,
-                                   top_n, use_kernel=use_kernel,
+                                   top_n, use_kernel=False,
                                    lut_int8=lut_int8)
     gids = jnp.where(lids >= 0, lids + me * n_loc, -1)
     return _gather_merge_batched(vals, gids, axes, n_shards,
@@ -252,7 +260,7 @@ def _local_scan_topn_rows(codes, queries, codebooks, rows, top_n: int,
 def sharded_adc_topn_rows(codes: jax.Array, queries: jax.Array,
                           codebooks: jax.Array, rows: jax.Array,
                           top_n: int, ctx: ShardCtx, *,
-                          use_kernel: bool = False, lut_int8: bool = False
+                          lut_int8: bool = False
                           ) -> Tuple[jax.Array, jax.Array]:
     """Executor stage ⑤, fused form (`fused=` plan knob): LUT build + ADC
     scan + partial top-k in one pipeline per shard, per-query candidate
@@ -268,18 +276,15 @@ def sharded_adc_topn_rows(codes: jax.Array, queries: jax.Array,
     if ctx.mesh is None:
         from repro.kernels.pq_adc.ops import pq_adc_fused_topk
         return pq_adc_fused_topk(codes, queries, codebooks, rows, top_n,
-                                 use_kernel=use_kernel, lut_int8=lut_int8)
+                                 use_kernel=False, lut_int8=lut_int8)
     axes = ctx.rules.corpus
     axes_t = (axes,) if isinstance(axes, str) else tuple(axes)
     n_shards = 1
     for a in axes_t:
         n_shards *= ctx.mesh.shape[a]
-    body = functools.partial(_local_scan_topn_rows, top_n=top_n,
-                             axes=axes_t, n_shards=n_shards,
-                             use_kernel=use_kernel, lut_int8=lut_int8)
-    return _shard_map(
-        body, mesh=ctx.mesh,
-        in_specs=(P(axes, None), P(None, None), P(None, None, None),
-                  P(None, None)),
-        out_specs=(P(None, None), P(None, None)),
-    )(codes, queries, codebooks, rows)
+    return _sharded_program(
+        _local_scan_topn_rows, ctx.mesh,
+        (P(axes, None), P(None, None), P(None, None, None), P(None, None)),
+        (P(None, None), P(None, None)),
+        top_n=top_n, axes=axes_t, n_shards=n_shards,
+        lut_int8=lut_int8)(codes, queries, codebooks, rows)

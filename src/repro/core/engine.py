@@ -66,14 +66,12 @@ class FusionANNSIndex:
     def __init__(self, cfg: ANNSConfig, codebook: pq.PQCodebook,
                  codes: jax.Array, posting: clustering.PostingLists,
                  graph: ng.NavGraph, ssd: SSDSim,
-                 use_kernel: bool = False,
                  rotation: Optional[np.ndarray] = None,
                  tombstones: Optional[np.ndarray] = None,
                  attributes=None, id_of: Optional[np.ndarray] = None):
         self.cfg = cfg
         self.codebook = codebook                 # HBM tier
         self.ssd = ssd                           # SSD tier: raw vectors
-        self.use_kernel = use_kernel             # Pallas interpret is slow on CPU
         # beyond-paper: OPQ rotation (core/opq.py); applied to queries
         # before the LUT build only — clustering/graph/re-rank raw space.
         self.rotation = rotation
@@ -421,7 +419,6 @@ class FusionANNSIndex:
             "n_rows": int(n_rows),
             "attr_sealed_cols": sorted(view.attrs.columns),
             "attr_delta_cols": sorted(view.delta.attrs.columns),
-            "use_kernel": bool(self.use_kernel),
             "cfg": dataclasses.asdict(self.cfg),
             "graph_entry": int(view.graph.entry),
             "ssd": {
@@ -488,7 +485,6 @@ class FusionANNSIndex:
         index = cls(cfg=cfg, codebook=pq.PQCodebook(
                         codebooks=jnp.asarray(arr["codebooks"])),
                     codes=codes, posting=posting, graph=graph, ssd=ssd,
-                    use_kernel=manifest["use_kernel"],
                     rotation=arr.get("rotation"),
                     tombstones=arr["tombstones"], id_of=id_of)
         # restore the delta + epoch too: a hydrated replica must answer

@@ -54,6 +54,7 @@ schema.
 from __future__ import annotations
 
 import dataclasses
+import functools
 import time
 from collections import deque
 from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
@@ -151,10 +152,11 @@ class QueryPlan:
         merged = {}
         if ov is not None:
             merged.update({f.name: getattr(ov, f.name)
-                           for f in dataclasses.fields(ov)})
-        merged.update(kw)
-        return dataclasses.replace(
-            self, **{name: v for name, v in merged.items() if v is not None})
+                           for f in dataclasses.fields(ov)
+                           if getattr(ov, f.name) is not None})
+        # a None keyword defers to the layers below; it never erases them
+        merged.update({n: v for n, v in kw.items() if v is not None})
+        return dataclasses.replace(self, **merged)
 
     def effective_depth(self) -> int:
         """In-flight window depth: explicit ``inflight_depth`` wins; else
@@ -206,6 +208,15 @@ class _Window:
     # ``_finish_into`` all read THIS epoch's binding, so a concurrent
     # insert/delete/compaction can never tear a window mid-pipeline
     view: Optional[object] = None
+
+
+@functools.lru_cache(maxsize=16)
+def _row_gather(out_sharding):
+    """Jitted row gather ``codes[rows]`` whose result lands with
+    ``out_sharding``: one program per placement, cached so a mesh
+    re-attach does not retrace it."""
+    return jax.jit(lambda codes, rows: jnp.take(codes, rows, axis=0),
+                   out_shardings=out_sharding)
 
 
 class _InflightQueue:
@@ -349,6 +360,11 @@ class QueryExecutor:
         outside the group."""
         from repro.sharding.spec import rules_for_mesh
         rules = rules_for_mesh(mesh)
+        # ``jax.make_mesh`` builds Explicit-typed axes, under which a
+        # gather from the row-sharded codes must name its out-sharding at
+        # every call site; the executor places every operand itself, so it
+        # runs on the same devices with Auto axes
+        mesh = jax.sharding.Mesh(mesh.devices, mesh.axis_names)
         # a router recarve may retarget this executor while a pump thread
         # is mid-dispatch: the ctx + placement-cache swap must not
         # interleave with a _device_codes() read of the old placement
@@ -441,15 +457,19 @@ class QueryExecutor:
         luts = pq.adc_lut_batch(idx.codebook, jnp.asarray(
             np.stack([idx._lut_query(np.asarray(q, np.float32))
                       for q in queries])))
-        cand = jnp.take(self._device_codes(view.codes), jnp.asarray(padded),
-                        axis=0)
+        codes = self._device_codes(view.codes)
         mask_dev = jnp.asarray(mask)
-        if self.ctx.mesh is not None:
+        if self.ctx.mesh is None:
+            cand = jnp.take(codes, jnp.asarray(padded), axis=0)
+        else:
             from jax.sharding import NamedSharding, PartitionSpec as P
             from repro.core.distributed import replicate_to_mesh
             corpus = self.ctx.rules.corpus
-            cand = jax.device_put(cand, NamedSharding(
-                self.ctx.mesh, P(corpus, None)))
+            # the gathered bucket lands row-sharded over the scan's shards
+            gather = _row_gather(NamedSharding(self.ctx.mesh,
+                                               P(corpus, None)))
+            cand = gather(codes,
+                          replicate_to_mesh(jnp.asarray(padded), self.ctx))
             mask_dev = jax.device_put(mask_dev, NamedSharding(
                 self.ctx.mesh, P(None, corpus)))
             # commit the LUTs too: on a SUB-mesh an uncommitted operand
@@ -458,8 +478,7 @@ class QueryExecutor:
             luts = replicate_to_mesh(luts, self.ctx)
         scan_top_n = max(p.top_n for p in plans)
         vals, pos = sharded_adc_topn_window(
-            cand, luts, mask_dev, min(scan_top_n, bucket), self.ctx,
-            use_kernel=idx.use_kernel)
+            cand, luts, mask_dev, min(scan_top_n, bucket), self.ctx)
         return _Window(queries=queries, plans=list(plans), per_q=per_q,
                        union=union, vals=vals, pos=pos, t_graph=t1 - t0,
                        t_scan_host=time.perf_counter() - t1, view=view,
@@ -500,8 +519,7 @@ class QueryExecutor:
         scan_top_n = max(p.top_n for p in plans)
         vals, gids = sharded_adc_topn_rows(
             self._device_codes(view.codes), qrot, codebooks, rows_dev,
-            min(scan_top_n, S), self.ctx, use_kernel=idx.use_kernel,
-            lut_int8=plans[0].lut_int8)
+            min(scan_top_n, S), self.ctx, lut_int8=plans[0].lut_int8)
         return _Window(queries=queries, plans=list(plans), per_q=per_q,
                        union=union, vals=vals, pos=gids, t_graph=t_graph,
                        t_scan_host=time.perf_counter() - t1,

@@ -5,6 +5,7 @@ machinery of Eq. (1)."""
 from __future__ import annotations
 
 import dataclasses
+import functools
 from typing import Optional, Tuple
 
 import jax
@@ -40,7 +41,15 @@ def _split_subspaces(x: jax.Array, m: int) -> jax.Array:
 def train_codebooks(rng: jax.Array, data: jax.Array, m: int,
                     nbits: int = 8, iters: int = 12) -> PQCodebook:
     """Vectorised per-sub-space k-means (Lloyd), k-means|| style sample init."""
-    k = 2 ** nbits
+    return PQCodebook(codebooks=_train(rng, data, m, 2 ** nbits, iters))
+
+
+# One program each: run op by op, the (M, N, K) distance block would be
+# materialised (32 GB at N = 1M, M = 32, K = 256); compiled, it fuses into
+# the argmin (about 1 GB of temporaries on a v5e).
+@functools.partial(jax.jit, static_argnames=("m", "k", "iters"))
+def _train(rng: jax.Array, data: jax.Array, m: int, k: int,
+           iters: int) -> jax.Array:
     sub = _split_subspaces(data.astype(jnp.float32), m)        # (M, N, ds)
     n = sub.shape[1]
     init_idx = jax.random.choice(rng, n, (k,), replace=n < k)
@@ -60,15 +69,20 @@ def train_codebooks(rng: jax.Array, data: jax.Array, m: int,
         return new, None
 
     centers, _ = jax.lax.scan(step, centers, None, length=iters)
-    return PQCodebook(codebooks=centers)
+    return centers
 
 
 def encode(cb: PQCodebook, data: jax.Array) -> jax.Array:
     """-> PQ codes (N, M) uint8 (nbits=8)."""
-    sub = _split_subspaces(data.astype(jnp.float32), cb.m)     # (M, N, ds)
+    return _encode(cb.codebooks, data)
+
+
+@jax.jit
+def _encode(codebooks: jax.Array, data: jax.Array) -> jax.Array:
+    sub = _split_subspaces(data.astype(jnp.float32), codebooks.shape[0])
     d2 = (jnp.sum(sub ** 2, -1)[:, :, None]
-          - 2.0 * jnp.einsum("mnd,mkd->mnk", sub, cb.codebooks)
-          + jnp.sum(cb.codebooks ** 2, -1)[:, None, :])
+          - 2.0 * jnp.einsum("mnd,mkd->mnk", sub, codebooks)
+          + jnp.sum(codebooks ** 2, -1)[:, None, :])
     return jnp.argmin(d2, axis=-1).T.astype(jnp.uint8)         # (N, M)
 
 

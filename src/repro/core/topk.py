@@ -15,7 +15,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.models.layers import ShardCtx
-from repro.sharding.spec import shard_map_compat
+from repro.sharding.spec import shard_map
 
 Axes = Union[str, Tuple[str, ...]]
 
@@ -62,7 +62,7 @@ def sharded_topk(scores: jax.Array, k: int, ctx: ShardCtx, *,
         vv, gg = local_topk_merge(v, gi, k)
         return sign * vv, gg
 
-    return shard_map_compat(
+    return shard_map(
         body, mesh=ctx.mesh,
         in_specs=P(b_spec, axes),
         out_specs=(P(b_spec, None), P(b_spec, None)),

@@ -13,6 +13,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from repro.kernels.backend import pallas_interpret
+
 
 def _l2_kernel(q_ref, v_ref, out_ref):
     q = q_ref[...].astype(jnp.float32)           # (bq, D)
@@ -25,7 +27,7 @@ def _l2_kernel(q_ref, v_ref, out_ref):
 
 
 def l2dist(queries: jax.Array, vectors: jax.Array, *, block_q: int = 128,
-           block_n: int = 512, interpret: bool = True) -> jax.Array:
+           block_n: int = 512) -> jax.Array:
     """(B, D) x (N, D) -> (B, N) f32.  B % block_q == 0, N % block_n == 0
     (ops.py pads)."""
     b, d = queries.shape
@@ -44,5 +46,5 @@ def l2dist(queries: jax.Array, vectors: jax.Array, *, block_q: int = 128,
         ],
         out_specs=pl.BlockSpec((bq, bn), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((b, n), jnp.float32),
-        interpret=interpret,
+        interpret=pallas_interpret(),
     )(queries, vectors)

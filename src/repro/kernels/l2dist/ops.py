@@ -11,10 +11,10 @@ from repro.kernels.l2dist.l2dist import l2dist
 from repro.kernels.l2dist.ref import l2dist_ref
 
 
-@functools.partial(jax.jit, static_argnames=("use_kernel", "interpret",
-                                             "block_q", "block_n"))
+@functools.partial(jax.jit, static_argnames=("use_kernel", "block_q",
+                                             "block_n"))
 def l2_distances(queries: jax.Array, vectors: jax.Array, *,
-                 use_kernel: bool = True, interpret: bool = True,
+                 use_kernel: bool = True,
                  block_q: int = 128, block_n: int = 512) -> jax.Array:
     if not use_kernel:
         return l2dist_ref(queries, vectors)
@@ -29,6 +29,5 @@ def l2_distances(queries: jax.Array, vectors: jax.Array, *,
     if pn:
         vectors = jnp.concatenate(
             [vectors, jnp.zeros((pn, d), vectors.dtype)], 0)
-    out = l2dist(queries, vectors, block_q=bq, block_n=bn,
-                 interpret=interpret)
+    out = l2dist(queries, vectors, block_q=bq, block_n=bn)
     return out[:b, :n]

@@ -1,4 +1,9 @@
-"""jit'd wrappers for the ADC kernels (padding + merge glue)."""
+"""jit'd wrappers for the ADC kernels (padding + merge glue).
+
+``use_kernel=True`` runs the Pallas kernel, which exists only in the CPU
+interpreter (the TPU compiler refuses it; ``kernels/backend.py`` raises on
+any other backend).  ``use_kernel=False`` is the XLA form the serving path
+runs on every backend, and the oracle the kernels are tested against."""
 
 from __future__ import annotations
 
@@ -22,39 +27,35 @@ def _pad_codes(codes: jax.Array, block_n: int):
     return codes, n, pad
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "use_kernel",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_n", "use_kernel"))
 def pq_adc(codes: jax.Array, lut: jax.Array, *, block_n: int = 2048,
-           use_kernel: bool = True, interpret: bool = True) -> jax.Array:
-    """distances (N,) f32.  use_kernel=False falls back to the jnp oracle
-    (identical results; used on CPU hot paths where interpret-mode Pallas
-    is slow)."""
+           use_kernel: bool = True) -> jax.Array:
+    """distances (N,) f32; ``use_kernel=False`` is the XLA form
+    (identical results)."""
     if not use_kernel:
         return pq_adc_ref(codes, lut)
     padded, n, pad = _pad_codes(codes, min(block_n, max(codes.shape[0], 8)))
     bn = min(block_n, padded.shape[0])
-    out = pq_adc_scan(padded, lut, block_n=bn, interpret=interpret)
+    out = pq_adc_scan(padded, lut, block_n=bn)
     return out[:n]
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "use_kernel",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("block_n", "use_kernel"))
 def pq_adc_batch(codes: jax.Array, luts: jax.Array, *, block_n: int = 2048,
-                 use_kernel: bool = True, interpret: bool = True):
+                 use_kernel: bool = True):
     """Batched queries: (N, M) x (B, M, K) -> (B, N) distances."""
     if not use_kernel:
         return pq_adc_batch_ref(codes, luts)
     padded, n, pad = _pad_codes(codes, min(block_n, max(codes.shape[0], 8)))
     bn = min(block_n, padded.shape[0])
-    out = pq_adc_scan_batch(padded, luts, block_n=bn, interpret=interpret)
+    out = pq_adc_scan_batch(padded, luts, block_n=bn)
     return out[:, :n]
 
 
-@functools.partial(jax.jit, static_argnames=("topk", "block_n", "use_kernel",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("topk", "block_n", "use_kernel"))
 def pq_adc_topk_batch(codes: jax.Array, luts: jax.Array, topk: int, *,
                       mask: jax.Array = None, block_n: int = 2048,
-                      use_kernel: bool = True, interpret: bool = True):
+                      use_kernel: bool = True):
     """Batched fused scan + per-query (optionally masked) top-k.
 
     codes (N, M) x luts (B, M, K) [x mask (B, N) bool] ->
@@ -62,19 +63,16 @@ def pq_adc_topk_batch(codes: jax.Array, luts: jax.Array, topk: int, *,
     ``mask`` is the executor's per-query candidate membership: False rows
     (other queries' candidates, padding) score +inf and sort last — this is
     the single-device form of the per-shard scan in core.distributed."""
-    d = pq_adc_batch(codes, luts, block_n=block_n, use_kernel=use_kernel,
-                     interpret=interpret)
+    d = pq_adc_batch(codes, luts, block_n=block_n, use_kernel=use_kernel)
     if mask is not None:
         d = jnp.where(mask, d, jnp.inf)
     neg, ids = jax.lax.top_k(-d, min(topk, d.shape[1]))
     return -neg, ids
 
 
-@functools.partial(jax.jit, static_argnames=("topk", "block_n", "use_kernel",
-                                             "interpret"))
+@functools.partial(jax.jit, static_argnames=("topk", "block_n", "use_kernel"))
 def pq_adc_topk(codes: jax.Array, lut: jax.Array, topk: int, *,
-                block_n: int = 2048, use_kernel: bool = True,
-                interpret: bool = True):
+                block_n: int = 2048, use_kernel: bool = True):
     """Fused scan + top-k: returns (dists (tk,), ids (tk,)) ascending with
     tk = min(topk, N) — only REAL rows, never padding.
 
@@ -96,8 +94,7 @@ def pq_adc_topk(codes: jax.Array, lut: jax.Array, topk: int, *,
     padded, n, pad = _pad_codes(codes, min(block_n, max(n, 8)))
     bn = min(block_n, padded.shape[0])
     tk = min(topk, bn)
-    vals, ids = pq_adc_scan_topk(padded, lut, tk, n=n, block_n=bn,
-                                 interpret=interpret)
+    vals, ids = pq_adc_scan_topk(padded, lut, tk, n=n, block_n=bn)
     neg, pos = jax.lax.top_k(-vals, tk_out)
     return -neg, ids[pos]
 
@@ -169,7 +166,7 @@ def _fused_rows_scan_int8(codes, q8, scale, zp, rows, topk: int):
 def pq_adc_fused_topk(codes: jax.Array, queries: jax.Array,
                       codebooks: jax.Array, rows: jax.Array, topk: int, *,
                       lut_int8: bool = False, use_kernel: bool = True,
-                      block_s: int = 2048, interpret: bool = True):
+                      block_s: int = 2048):
     """The fused query pipeline (ISSUE-6 tentpole): LUT build -> ADC scan
     -> partial top-k over each query's OWN candidate rows, one device
     round-trip per scan window.
@@ -184,10 +181,9 @@ def pq_adc_fused_topk(codes: jax.Array, queries: jax.Array,
     padding row id.
 
     ``use_kernel=True`` runs the single Pallas kernel (LUT resident in
-    VMEM across the grid, int8 scratch under ``lut_int8``);
-    ``use_kernel=False`` is the CPU hot path: a tiny LUT-build dispatch
-    plus ONE fused gather/scan/top-k jit (2.2-3.4x the unfused dense
-    masked scan at fig9 shapes — see benchmarks/kernels_bench.py)."""
+    VMEM across the grid, int8 scratch under ``lut_int8``; CPU interpreter
+    only).  ``use_kernel=False`` is the XLA form the serving path runs: a
+    tiny LUT-build dispatch plus ONE fused gather/scan/top-k jit."""
     b, s = rows.shape
     tk_out = min(topk, s)
     if not use_kernel:
@@ -202,7 +198,6 @@ def pq_adc_fused_topk(codes: jax.Array, queries: jax.Array,
         rows = jnp.concatenate(
             [rows, jnp.full((b, pad), -1, rows.dtype)], axis=1)
     vals, ids = pq_adc_scan_fused(codes, queries, codebooks, rows, tk_out,
-                                  block_s=bs, lut_int8=lut_int8,
-                                  interpret=interpret)
+                                  block_s=bs, lut_int8=lut_int8)
     neg, pos = jax.lax.top_k(-vals, min(tk_out, vals.shape[1]))
     return -neg, jnp.take_along_axis(ids, pos, axis=1)

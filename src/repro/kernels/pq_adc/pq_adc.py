@@ -9,6 +9,12 @@ Unlike the paper's CUDA kernel (one thread per dimension + coordinator
 accumulation + spinlock hash dedup), the TPU formulation is a vectorised
 flat-index gather over the VMEM-resident LUT with a sum over M — no atomics
 exist in Pallas and none are needed (dedup is a separate sort-based pass).
+
+The TPU compiler refuses all four kernels as written: the flat 1-D gathers
+of ``pq_adc_scan`` / ``pq_adc_scan_topk`` ("Only 2D gather is supported")
+and the batched gathers of ``pq_adc_scan_batch`` / ``pq_adc_scan_fused``
+("Shape mismatch in input, indices and output").  They run only in the
+CPU interpreter (``kernels/backend.py``); the serving path scans in XLA.
 """
 
 from __future__ import annotations
@@ -20,6 +26,8 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+from repro.kernels.backend import interpret_or_refuse
+
 
 def _adc_kernel(codes_ref, lut_ref, out_ref, *, m: int, k: int):
     codes = codes_ref[...]                       # (block_n, M) uint8
@@ -30,11 +38,12 @@ def _adc_kernel(codes_ref, lut_ref, out_ref, *, m: int, k: int):
     out_ref[...] = jnp.sum(vals.reshape(codes.shape), axis=-1)
 
 
-def pq_adc_scan(codes: jax.Array, lut: jax.Array, *, block_n: int = 2048,
-                interpret: bool = True) -> jax.Array:
+def pq_adc_scan(codes: jax.Array, lut: jax.Array, *,
+                block_n: int = 2048) -> jax.Array:
     """codes (N, M) uint8, lut (M, K) f32 -> distances (N,) f32.
 
     N must be a multiple of block_n (callers pad; ops.py handles it)."""
+    interpret = interpret_or_refuse("pq_adc_scan")
     n, m = codes.shape
     mk, k = lut.shape
     assert mk == m, (m, mk)
@@ -70,9 +79,9 @@ def _adc_batch_kernel(codes_ref, luts_ref, out_ref, *, m: int, k: int,
 
 
 def pq_adc_scan_batch(codes: jax.Array, luts: jax.Array, *,
-                      block_n: int = 2048,
-                      interpret: bool = True) -> jax.Array:
+                      block_n: int = 2048) -> jax.Array:
     """codes (N, M) uint8, luts (B, M, K) f32 -> distances (B, N) f32."""
+    interpret = interpret_or_refuse("pq_adc_scan_batch")
     n, m = codes.shape
     b, mk, k = luts.shape
     assert mk == m and n % block_n == 0
@@ -116,14 +125,14 @@ def _adc_topk_kernel(codes_ref, lut_ref, vals_ref, idx_ref, *,
 
 
 def pq_adc_scan_topk(codes: jax.Array, lut: jax.Array, topk: int, *,
-                     n: int = None, block_n: int = 2048,
-                     interpret: bool = True):
+                     n: int = None, block_n: int = 2048):
     """Fused ADC scan + block-local top-k.
 
     ``n`` is the REAL row count (rows past it are padding, masked to +inf
     inside each block before its partial top-k).  Returns
     (vals (n_blocks*topk,), global_ids (n_blocks*topk,)); callers finish
     with one small lax.top_k merge (ops.pq_adc_topk)."""
+    interpret = interpret_or_refuse("pq_adc_scan_topk")
     n_padded, m = codes.shape
     _, k = lut.shape
     if n is None:
@@ -214,8 +223,7 @@ def _adc_fused_kernel(rows_ref, codes_ref, queries_ref, cb_ref,
 
 def pq_adc_scan_fused(codes: jax.Array, queries: jax.Array,
                       codebooks: jax.Array, rows: jax.Array, topk: int, *,
-                      block_s: int = 2048, lut_int8: bool = False,
-                      interpret: bool = True):
+                      block_s: int = 2048, lut_int8: bool = False):
     """Fused LUT->ADC->top-k over per-query candidate rows.
 
     codes (N, M) uint8 resident; queries (B, M*dsub) f32 (rotation already
@@ -224,6 +232,7 @@ def pq_adc_scan_fused(codes: jax.Array, queries: jax.Array,
     (vals (B, n_blocks*tk), ids (B, n_blocks*tk)) with tk =
     min(topk, block_s); callers finish with one small merge
     (ops.pq_adc_fused_topk)."""
+    interpret = interpret_or_refuse("pq_adc_scan_fused")
     n, m = codes.shape
     mk, k, dsub = codebooks.shape
     b, s = rows.shape

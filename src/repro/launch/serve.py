@@ -18,6 +18,7 @@ from repro.configs.anns_datasets import SIFT_SMALL
 from repro.configs.registry import ARCH_IDS, get_config
 from repro.core.engine import FusionANNSIndex, ground_truth, recall_at_k
 from repro.data.synthetic import clustered_vectors
+from repro.launch.compile_cache import enable_compile_cache
 from repro.models import transformer as tfm
 from repro.serve.engine import LMServer, ServeConfig
 
@@ -63,6 +64,7 @@ def serve_lm(args) -> None:
 
 
 def main() -> None:
+    enable_compile_cache()
     ap = argparse.ArgumentParser()
     ap.add_argument("--mode", choices=("anns", "lm"), default="anns")
     ap.add_argument("--n", type=int, default=20000)

@@ -22,7 +22,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.configs.base import GNNConfig
 from repro.models.layers import ShardCtx, LOCAL_CTX
-from repro.sharding.spec import Rules, shard_map_compat
+from repro.sharding.spec import Rules, shard_map
 
 
 def init_sage(rng: jax.Array, cfg: GNNConfig,
@@ -141,7 +141,7 @@ def sage_forward_full_dstpart(params, feats, edges, weights,
         return _sage_layer(h1_l, neigh2, p2, final=True)
 
     pspec = jax.tree_util.tree_map(lambda x: P(*([None] * x.ndim)), p1)
-    return shard_map_compat(
+    return shard_map(
         body, mesh=ctx.mesh,
         in_specs=(P(None, None), P(axes, None), P(axes), pspec, pspec),
         out_specs=P(axes, None),

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
 from repro.configs.base import LMConfig
-from repro.sharding.spec import Rules, shard_map_compat
+from repro.sharding.spec import Rules, shard_map
 
 
 @dataclasses.dataclass(frozen=True)
@@ -408,7 +408,7 @@ def moe_block(x: jax.Array, router_w, w1, w2, shared_w1, shared_w2,
                     cfg=cfg, axis=axis, n_shards=n_shards)
             return yl.reshape(b, s, d)
 
-        out = shard_map_compat(
+        out = shard_map(
             body, mesh=ctx.mesh,
             in_specs=(x_spec, P(None, None),
                       P(r.expert, None, None), P(r.expert, None, None)),

@@ -76,20 +76,11 @@ def local_rules_for_mesh(mesh: Mesh) -> Rules:
     return rules_for_mesh(mesh)
 
 
-if hasattr(jax, "shard_map"):                      # jax >= 0.6 spelling
-    def shard_map_compat(body, mesh, in_specs, out_specs):
-        """``jax.shard_map`` across jax versions (0.4.x moved it under
-        ``jax.experimental`` and called the check flag ``check_rep``)."""
-        return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-else:                                              # jax 0.4.x spelling
-    from jax.experimental.shard_map import shard_map as _shard_map_04
-
-    def shard_map_compat(body, mesh, in_specs, out_specs):
-        """``jax.shard_map`` across jax versions (0.4.x moved it under
-        ``jax.experimental`` and called the check flag ``check_rep``)."""
-        return _shard_map_04(body, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_rep=False)
+def shard_map(body, mesh, in_specs, out_specs):
+    """``jax.shard_map`` without the varying-manual-axes check (the bodies
+    here all_gather and merge explicitly)."""
+    return jax.shard_map(body, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def constrain(x, mesh: Mesh, spec: P):

@@ -128,6 +128,7 @@ def sharded_results():
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"    # a CPU rehearsal: never reach for a chip
     proc = subprocess.run(
         [sys.executable, "-c", _SHARDED_SCRIPT, os.path.abspath(src)],
         capture_output=True, text=True, timeout=600, env=env)

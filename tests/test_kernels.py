@@ -1,4 +1,6 @@
-"""Per-kernel shape/dtype sweeps: Pallas (interpret=True) vs pure-jnp oracle."""
+"""Per-kernel shape/dtype sweeps: Pallas (the CPU interpreter) vs the
+pure-jnp oracle, and the fence that keeps the ADC kernels off other
+backends."""
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +31,7 @@ def test_pq_adc_lut_widths(rng, k_entries):
     n, m = 128, 8
     codes = jnp.asarray(rng.integers(0, k_entries, (n, m)), jnp.uint8)
     lut = jnp.asarray(rng.random((m, k_entries)), jnp.float32)
-    out = pq_adc_scan(codes, lut, block_n=64, interpret=True)
+    out = pq_adc_scan(codes, lut, block_n=64)
     np.testing.assert_allclose(np.asarray(out),
                                np.asarray(pq_adc_ref(codes, lut)), rtol=1e-6)
 
@@ -80,6 +82,49 @@ def test_pq_adc_topk_n_below_topk_returns_only_real_rows(rng, n, topk,
     assert vals.shape == (min(topk, n),)
     assert np.all(np.isfinite(np.asarray(vals)))
     assert np.all((np.asarray(ids) >= 0) & (np.asarray(ids) < n))
+
+
+_N_FENCE, _M_FENCE, _B_FENCE = 72, 8, 3     # shapes no other test traces
+
+
+@pytest.mark.parametrize("entry,kernel", [
+    ("pq_adc", "pq_adc_scan"),
+    ("pq_adc_batch", "pq_adc_scan_batch"),
+    ("pq_adc_topk", "pq_adc_scan_topk"),
+    ("pq_adc_topk_batch", "pq_adc_scan_batch"),
+    ("pq_adc_fused_topk", "pq_adc_scan_fused"),
+])
+def test_adc_kernel_refused_off_cpu(rng, monkeypatch, entry, kernel):
+    """On a backend other than the CPU, ``use_kernel=True`` raises an error
+    naming the kernel: it never reaches the Pallas interpreter and never
+    falls back to the jnp form."""
+    import importlib
+    import repro.kernels.pq_adc as ops
+    # the package re-exports a function named like the kernel module
+    kmod = importlib.import_module("repro.kernels.pq_adc.pq_adc")
+
+    def interpreted(*a, **kw):
+        raise AssertionError("pallas_call reached off the CPU")
+
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    monkeypatch.setattr(kmod.pl, "pallas_call", interpreted)
+    n, m, b = _N_FENCE, _M_FENCE, _B_FENCE
+    codes = jnp.asarray(rng.integers(0, 256, (n, m)), jnp.uint8)
+    lut = jnp.asarray(rng.random((m, 256)), jnp.float32)
+    luts = jnp.asarray(rng.random((b, m, 256)), jnp.float32)
+    calls = {
+        "pq_adc": lambda: ops.pq_adc(codes, lut),
+        "pq_adc_batch": lambda: ops.pq_adc_batch(codes, luts),
+        "pq_adc_topk": lambda: ops.pq_adc_topk(codes, lut, 9),
+        "pq_adc_topk_batch": lambda: ops.pq_adc_topk_batch(codes, luts, 9),
+        "pq_adc_fused_topk": lambda: ops.pq_adc_fused_topk(
+            codes, jnp.zeros((b, m * 4), jnp.float32),
+            jnp.zeros((m, 256, 4), jnp.float32),
+            jnp.zeros((b, 16), jnp.int32), 9),
+    }
+    with pytest.raises(NotImplementedError,
+                       match=rf"{kernel} does not compile for this chip"):
+        calls[entry]()
 
 
 def _fused_rows_case(rng, n, m, b, S, k=256, dsub=4):

@@ -90,6 +90,7 @@ print(json.dumps({"f4": run(4), "f16": run(16)}))
     src = os.path.join(os.path.dirname(__file__), "..", "src")
     env = dict(os.environ)
     env.pop("XLA_FLAGS", None)
+    env["JAX_PLATFORMS"] = "cpu"    # a CPU rehearsal: never reach for a chip
     proc = subprocess.run([sys.executable, "-c", script,
                            os.path.abspath(src)],
                           capture_output=True, text=True, timeout=300,

@@ -3,6 +3,7 @@ epoch-stamped view publication, background compaction, and snapshot
 checkpoint/restore parity."""
 
 import copy
+import json
 import time
 
 import numpy as np
@@ -182,9 +183,18 @@ def _assert_bit_identical(a: FusionANNSIndex, b: FusionANNSIndex, queries):
         np.testing.assert_array_equal(ra.dists, rb.dists)
 
 
-def test_snapshot_roundtrip_sealed_only(index_and_data, tmp_path):
+@pytest.mark.parametrize("legacy_use_kernel", [None, True])
+def test_snapshot_roundtrip_sealed_only(index_and_data, tmp_path,
+                                        legacy_use_kernel):
+    """``legacy_use_kernel``: manifests written before the scan was chosen
+    by platform carry a ``use_kernel`` field; they still load."""
     cfg, data, new_vecs, queries, index = index_and_data
     index.save_snapshot(str(tmp_path / "snap"))
+    if legacy_use_kernel is not None:
+        path = tmp_path / "snap" / "manifest.json"
+        manifest = json.loads(path.read_text())
+        manifest["use_kernel"] = legacy_use_kernel
+        path.write_text(json.dumps(manifest))
     restored = FusionANNSIndex.load_snapshot(str(tmp_path / "snap"))
     assert restored.epoch == index.epoch
     assert restored.n_total == index.n_total
