@@ -136,6 +136,14 @@ def serve(index, queries, clock: CompileClock, label: str, **stack_kw):
     return resps, router
 
 
+def agree(name: str, ref, ids, ref_name: str) -> bool:
+    """Print how many queries' top-k ids equal the reference's."""
+    import numpy as np
+    n_same = int(np.sum(np.all(ref == ids, axis=1)))
+    print(f"{name} ids == {ref_name} ids: {n_same} of {len(ref)} queries")
+    return n_same == len(ref)
+
+
 def ids_of(results):
     import numpy as np
     return np.stack([np.asarray(r.ids) for r in results])
@@ -158,9 +166,8 @@ def one_chip(seed: int, clock: CompileClock, n: int = N_SIFT1M,
     print(f"recall@{cfg.top_k}: {recall:.4f} over {len(queries)} queries")
     check(recall >= 0.9, f"recall@10 {recall:.4f} < 0.9")
     fused, _ = serve(index, queries, clock, "fused", fused=True)
-    same = bool(np.array_equal(ids_of(dense), ids_of(fused)))
-    print(f"fused ids == dense ids: {same}")
-    check(same, "fused top-k ids differ from the dense scan's")
+    check(agree("fused", ids_of(dense), ids_of(fused), "dense"),
+          "fused top-k ids differ from the dense scan's")
 
 
 def placement_rows(executor):
@@ -204,10 +211,8 @@ def four_chips(seed: int, clock: CompileClock, n: int = N_SIFT1M,
     check(sorted(d for g in groups for d in g) == sorted(d.id for d in
                                                          jax.devices()),
           "the 4 replicas do not each hold the codes on their own device")
-    for name, ids in got.items():
-        same = bool(np.array_equal(ref, ids))
-        print(f"{name} ids == one-device ids: {same}")
-        check(same, f"{name} top-k ids differ from the one-device executor")
+    same = [agree(name, ref, ids, "one-device") for name, ids in got.items()]
+    check(all(same), "top-k ids differ from the one-device executor")
 
 
 def main(argv=None) -> int:

@@ -70,12 +70,13 @@ def _sharded_program(body, mesh: Mesh, in_specs, out_specs, **static):
 
 def _gather_merge_batched(vals, gids, axes, n_shards: int, tk_out: int):
     """Shared tail of the batched shard bodies: all_gather the per-shard
-    (dist, global-id) pairs along the query-local axis and merge."""
+    (dist, global-id) pairs along the query-local axis and merge by the
+    same (dist, id) order each shard selected with."""
+    from repro.kernels.pq_adc.ops import smallest_k
     if n_shards > 1:
         vals = jax.lax.all_gather(vals, axes, axis=1, tiled=True)
         gids = jax.lax.all_gather(gids, axes, axis=1, tiled=True)
-    neg, pos = jax.lax.top_k(-vals, tk_out)
-    return -neg, jnp.take_along_axis(gids, pos, axis=1)
+    return smallest_k(vals, gids, tk_out)
 
 
 def _local_scan_topn(codes, lut, top_n: int, axes, n_shards: int):
@@ -190,39 +191,49 @@ def sharded_adc_topn_batch(codes: jax.Array, luts: jax.Array, top_n: int,
     )(codes, luts)
 
 
-def _local_scan_topn_window(codes, luts, mask, top_n: int, axes,
+def _local_scan_topn_window(codes, rows, luts, mask, top_n: int, axes,
                             n_shards: int):
-    """Per-shard body of the executor's windowed scan: score this shard's
-    candidate rows for all B queries, mask non-members / padding to +inf,
-    take a per-shard per-query top-n, and all_gather only the (distance,
-    global-position) pairs before the global merge."""
+    """Per-shard body of the executor's windowed scan.  ``rows`` (bucket,)
+    holds the window's candidate union as GLOBAL code rows (replicated):
+    this shard scores the bucket positions whose rows it holds, masks the
+    rest, non-members and padding to +inf, takes a per-query top-n, and
+    all_gathers only (distance, bucket-position) pairs — no code row
+    crosses the interconnect."""
+    from repro.kernels.pq_adc.ops import smallest_k
     from repro.kernels.pq_adc.ref import pq_adc_batch_ref
     n_loc = codes.shape[0]
-    dist = pq_adc_batch_ref(codes, luts)                      # (B, n_loc)
-    dist = jnp.where(mask, dist, jnp.inf)
-    tk = min(top_n, n_loc)
-    neg, idx = jax.lax.top_k(-dist, tk)
     me = jax.lax.axis_index(axes) if n_shards > 1 else 0
-    gids = idx + me * n_loc
-    return _gather_merge_batched(-neg, gids, axes, n_shards,
-                                 min(top_n, n_loc * n_shards))
+    local = rows - me * n_loc
+    mine = (local >= 0) & (local < n_loc)
+    cand = jnp.take(codes, jnp.clip(local, 0, n_loc - 1), axis=0)
+    dist = pq_adc_batch_ref(cand, luts)                       # (B, bucket)
+    dist = jnp.where(mask & mine[None, :], dist, jnp.inf)
+    tk = min(top_n, rows.shape[0])
+    vals, pos = smallest_k(
+        dist, jax.lax.broadcasted_iota(jnp.int32, dist.shape, 1), tk)
+    return _gather_merge_batched(vals, pos, axes, n_shards, tk)
 
 
-def sharded_adc_topn_window(codes: jax.Array, luts: jax.Array,
-                            mask: jax.Array, top_n: int, ctx: ShardCtx
-                            ) -> Tuple[jax.Array, jax.Array]:
+_window_scan_one = jax.jit(_local_scan_topn_window,
+                           static_argnames=("top_n", "axes", "n_shards"))
+
+
+def sharded_adc_topn_window(codes: jax.Array, rows: jax.Array,
+                            luts: jax.Array, mask: jax.Array, top_n: int,
+                            ctx: ShardCtx) -> Tuple[jax.Array, jax.Array]:
     """Executor stage ⑤: candidate-bucket scan with per-query membership.
 
-    codes (N, M) uint8 row-sharded over the ``corpus`` axes; luts (B, M, K)
-    and mask (B, N) bool (True where row N is one of query B's candidates;
-    padding rows all-False) -> (dists (B, tk), bucket positions (B, tk))
-    replicated, tk = min(top_n, N).  Masked-out slots surface as +inf.
-    Single-device (``ctx.mesh is None``) runs the same scan as one
-    program — identical results, so sharded == unsharded is testable."""
+    codes (N, M) uint8 (row-sharded over the ``corpus`` axes on a mesh);
+    rows (U,) int32 the window's candidate union as code rows (padding:
+    any row, masked); luts (B, M, K); mask (B, U) bool, True where bucket
+    slot u is one of query b's candidates -> (dists (B, tk), bucket
+    positions (B, tk)) replicated, tk = min(top_n, U).  Masked-out slots
+    surface as +inf.  One device runs the same body as one shard: every
+    shard scores the whole (B, U) bucket shape and selects by (distance,
+    position), so sharded == unsharded exactly."""
     if ctx.mesh is None:
-        from repro.kernels.pq_adc.ops import pq_adc_topk_batch
-        return pq_adc_topk_batch(codes, luts, top_n, mask=mask,
-                                 use_kernel=False)
+        return _window_scan_one(codes, rows, luts, mask, top_n=top_n,
+                                axes=(), n_shards=1)
     axes = ctx.rules.corpus
     axes_t = (axes,) if isinstance(axes, str) else tuple(axes)
     n_shards = 1
@@ -230,9 +241,9 @@ def sharded_adc_topn_window(codes: jax.Array, luts: jax.Array,
         n_shards *= ctx.mesh.shape[a]
     return _sharded_program(
         _local_scan_topn_window, ctx.mesh,
-        (P(axes, None), P(None, None, None), P(None, axes)),
+        (P(axes, None), P(None), P(None, None, None), P(None, None)),
         (P(None, None), P(None, None)),
-        top_n=top_n, axes=axes_t, n_shards=n_shards)(codes, luts, mask)
+        top_n=top_n, axes=axes_t, n_shards=n_shards)(codes, rows, luts, mask)
 
 
 def _local_scan_topn_rows(codes, queries, codebooks, rows, top_n: int,

@@ -54,7 +54,6 @@ schema.
 from __future__ import annotations
 
 import dataclasses
-import functools
 import time
 from collections import deque
 from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
@@ -208,15 +207,6 @@ class _Window:
     # ``_finish_into`` all read THIS epoch's binding, so a concurrent
     # insert/delete/compaction can never tear a window mid-pipeline
     view: Optional[object] = None
-
-
-@functools.lru_cache(maxsize=16)
-def _row_gather(out_sharding):
-    """Jitted row gather ``codes[rows]`` whose result lands with
-    ``out_sharding``: one program per placement, cached so a mesh
-    re-attach does not retrace it."""
-    return jax.jit(lambda codes, rows: jnp.take(codes, rows, axis=0),
-                   out_shardings=out_sharding)
 
 
 class _InflightQueue:
@@ -440,13 +430,11 @@ class QueryExecutor:
                                         view=view, t_graph=t1 - t0,
                                         prefilter=prefilter)
         u = len(union)
-        shards = self._n_shards()
-        bucket = max(64, shards, 1 << int(np.ceil(np.log2(max(u, 1)))))
-        bucket += (-bucket) % shards
-        # physical code rows for the gather: ids and rows diverge once a
+        bucket = max(64, 1 << int(np.ceil(np.log2(max(u, 1)))))
+        # physical code rows of the union: ids and rows diverge once a
         # seal-time purge has run (view.row_of maps id -> row; union never
         # contains a purged id because the tombstone filter ran first)
-        padded = np.zeros(bucket, np.int64)
+        padded = np.zeros(bucket, np.int32)
         padded[:u] = view.row_of[union]
         # per-query membership: only a query's own candidates compete in its
         # top-n (identical semantics at every window size)
@@ -457,28 +445,18 @@ class QueryExecutor:
         luts = pq.adc_lut_batch(idx.codebook, jnp.asarray(
             np.stack([idx._lut_query(np.asarray(q, np.float32))
                       for q in queries])))
-        codes = self._device_codes(view.codes)
-        mask_dev = jnp.asarray(mask)
-        if self.ctx.mesh is None:
-            cand = jnp.take(codes, jnp.asarray(padded), axis=0)
-        else:
-            from jax.sharding import NamedSharding, PartitionSpec as P
+        rows_dev, mask_dev = jnp.asarray(padded), jnp.asarray(mask)
+        if self.ctx.mesh is not None:
             from repro.core.distributed import replicate_to_mesh
-            corpus = self.ctx.rules.corpus
-            # the gathered bucket lands row-sharded over the scan's shards
-            gather = _row_gather(NamedSharding(self.ctx.mesh,
-                                               P(corpus, None)))
-            cand = gather(codes,
-                          replicate_to_mesh(jnp.asarray(padded), self.ctx))
-            mask_dev = jax.device_put(mask_dev, NamedSharding(
-                self.ctx.mesh, P(None, corpus)))
-            # commit the LUTs too: on a SUB-mesh an uncommitted operand
-            # sits on the process default device, which may belong to a
-            # sibling replica's group — compute must follow THIS mesh
-            luts = replicate_to_mesh(luts, self.ctx)
+            # commit every operand to THIS mesh: on a SUB-mesh an
+            # uncommitted one sits on the process default device, which
+            # may belong to a sibling replica's group
+            rows_dev, mask_dev, luts = (replicate_to_mesh(x, self.ctx)
+                                        for x in (rows_dev, mask_dev, luts))
         scan_top_n = max(p.top_n for p in plans)
         vals, pos = sharded_adc_topn_window(
-            cand, luts, mask_dev, min(scan_top_n, bucket), self.ctx)
+            self._device_codes(view.codes), rows_dev, luts, mask_dev,
+            min(scan_top_n, bucket), self.ctx)
         return _Window(queries=queries, plans=list(plans), per_q=per_q,
                        union=union, vals=vals, pos=pos, t_graph=t1 - t0,
                        t_scan_host=time.perf_counter() - t1, view=view,

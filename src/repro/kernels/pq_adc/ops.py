@@ -18,6 +18,17 @@ from repro.kernels.pq_adc.ref import (build_luts_ref, pq_adc_batch_ref,
                                       pq_adc_ref)
 
 
+def smallest_k(vals: jax.Array, ids: jax.Array, k: int):
+    """The ``k`` smallest ``(val, id)`` pairs along the last axis, ordered
+    by value, then id.  ``lax.top_k`` breaks ties by array position, and
+    after a shard merge's all-gather that position follows the shards,
+    not the ids.  PQ distances tie often (vectors of one cluster share
+    codes), so the dense, fused and sharded scans all select their top-n
+    by this one total order, whatever layout the selection sees."""
+    v, i = jax.lax.sort((vals, ids), dimension=vals.ndim - 1, num_keys=2)
+    return v[..., :k], i[..., :k]
+
+
 def _pad_codes(codes: jax.Array, block_n: int):
     n = codes.shape[0]
     pad = (-n) % block_n
@@ -61,13 +72,13 @@ def pq_adc_topk_batch(codes: jax.Array, luts: jax.Array, topk: int, *,
     codes (N, M) x luts (B, M, K) [x mask (B, N) bool] ->
     (dists (B, tk), row indices (B, tk)) ascending, tk = min(topk, N).
     ``mask`` is the executor's per-query candidate membership: False rows
-    (other queries' candidates, padding) score +inf and sort last — this is
-    the single-device form of the per-shard scan in core.distributed."""
+    (other queries' candidates, padding) score +inf and sort last, as in
+    the executor's window scan (``core.distributed``)."""
     d = pq_adc_batch(codes, luts, block_n=block_n, use_kernel=use_kernel)
     if mask is not None:
         d = jnp.where(mask, d, jnp.inf)
-    neg, ids = jax.lax.top_k(-d, min(topk, d.shape[1]))
-    return -neg, ids
+    pos = jax.lax.broadcasted_iota(jnp.int32, d.shape, 1)
+    return smallest_k(d, pos, min(topk, d.shape[1]))
 
 
 @functools.partial(jax.jit, static_argnames=("topk", "block_n", "use_kernel"))
@@ -137,10 +148,9 @@ def _fused_rows_scan(codes, luts, rows, topk: int):
     flat = luts.reshape(-1)
     d = jnp.sum(flat.at[idx].get(mode="promise_in_bounds"), axis=-1)
     d = jnp.where(rows >= 0, d, jnp.inf)
-    neg, pos = jax.lax.top_k(-d, min(topk, s))
     # rows carries -1 at pad slots, so ids inherit the "no candidate"
     # marker for free (+inf distance rides along)
-    return -neg, jnp.take_along_axis(rows, pos, axis=1)
+    return smallest_k(d, rows, min(topk, s))
 
 
 @functools.partial(jax.jit, static_argnames=("topk",))
@@ -159,8 +169,7 @@ def _fused_rows_scan_int8(codes, q8, scale, zp, rows, topk: int):
         mode="promise_in_bounds").astype(jnp.float32)         # (B, S, M)
     d = jnp.sum((g + 128.0) * scale[:, None, :] + zp[:, None, :], axis=-1)
     d = jnp.where(rows >= 0, d, jnp.inf)
-    neg, pos = jax.lax.top_k(-d, min(topk, s))
-    return -neg, jnp.take_along_axis(rows, pos, axis=1)
+    return smallest_k(d, rows, min(topk, s))
 
 
 def pq_adc_fused_topk(codes: jax.Array, queries: jax.Array,

@@ -70,15 +70,18 @@ def test_stats_accounting_invariants(paths):
 
 
 def test_masked_topk_batch_matches_reference(rng):
-    """pq_adc_topk_batch (the executor's single-device scan) == brute ref."""
-    from repro.kernels.pq_adc.ops import pq_adc_topk_batch
+    """The executor's single-device bucket scan == brute ref over the
+    gathered bucket rows."""
+    from repro.core.distributed import sharded_adc_topn_window
     from repro.kernels.pq_adc.ref import pq_adc_batch_ref
-    codes = jnp.asarray(rng.integers(0, 256, (512, 8)), jnp.uint8)
+    from repro.models.layers import ShardCtx
+    codes = jnp.asarray(rng.integers(0, 256, (2048, 8)), jnp.uint8)
+    rows = jnp.asarray(rng.choice(2048, 512, replace=False), jnp.int32)
     luts = jnp.asarray(rng.random((3, 8, 256)), jnp.float32)
     mask = jnp.asarray(rng.random((3, 512)) < 0.5)
-    vals, pos = pq_adc_topk_batch(codes, luts, 32, mask=mask,
-                                  use_kernel=False)
-    ref = np.asarray(pq_adc_batch_ref(codes, luts))
+    vals, pos = sharded_adc_topn_window(codes, rows, luts, mask, 32,
+                                        ShardCtx())
+    ref = np.asarray(pq_adc_batch_ref(codes[rows], luts))
     ref = np.where(np.asarray(mask), ref, np.inf)
     for qb in range(3):
         expect = np.sort(ref[qb])[:32]

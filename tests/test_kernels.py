@@ -84,6 +84,19 @@ def test_pq_adc_topk_n_below_topk_returns_only_real_rows(rng, n, topk,
     assert np.all((np.asarray(ids) >= 0) & (np.asarray(ids) < n))
 
 
+def test_smallest_k_orders_ties_by_id(rng):
+    """The scans' top-n is the k smallest (dist, id) pairs: among equal
+    distances the lower id wins, whatever order the ids arrive in."""
+    from repro.kernels.pq_adc.ops import smallest_k
+    vals = rng.integers(0, 5, (3, 200)).astype(np.float32)
+    ids = np.stack([rng.permutation(200) for _ in range(3)]).astype(np.int32)
+    v, i = smallest_k(jnp.asarray(vals), jnp.asarray(ids), 37)
+    for q in range(3):
+        order = np.lexsort((ids[q], vals[q]))[:37]
+        np.testing.assert_array_equal(np.asarray(v[q]), vals[q][order])
+        np.testing.assert_array_equal(np.asarray(i[q]), ids[q][order])
+
+
 _N_FENCE, _M_FENCE, _B_FENCE = 72, 8, 3     # shapes no other test traces
 
 

@@ -68,8 +68,8 @@ def _device_bytes(compiled) -> int:
             + m.temp_size_in_bytes)
 
 
-@pytest.mark.parametrize("m,s", [(32, 1024), (32, 8192), (25, 8192),
-                                 (24, 8192)])
+@pytest.mark.parametrize("m,s", [(32, 1024), (32, 8192), (25, 1024),
+                                 (24, 1024)])
 def test_fused_rows_scan_compiles(one_chip, m, s):
     from repro.kernels.pq_adc.ops import _fused_rows_scan
     compiled = _fused_rows_scan.lower(
@@ -79,15 +79,12 @@ def test_fused_rows_scan_compiles(one_chip, m, s):
     assert _device_bytes(compiled) < HBM_BYTES
 
 
-@pytest.mark.parametrize("bucket", [BUCKET, 1 << 17])
-def test_dense_bucket_scan_fits_one_chip(one_chip, bucket):
-    """The smoke deployment's bucket, and 16x that: the (B, bucket, M)
-    gather grows linearly with the bucket."""
+def test_dense_bucket_scan_fits_one_chip(one_chip):
     from repro.kernels.pq_adc.ops import pq_adc_topk_batch
     compiled = pq_adc_topk_batch.lower(
-        _spec((bucket, 32), jnp.uint8, one_chip),
+        _spec((BUCKET, 32), jnp.uint8, one_chip),
         _spec((B, 32, 256), jnp.float32, one_chip), 512,
-        mask=_spec((B, bucket), jnp.bool_, one_chip),
+        mask=_spec((B, BUCKET), jnp.bool_, one_chip),
         use_kernel=False).compile()
     assert _device_bytes(compiled) < HBM_BYTES
 
@@ -120,24 +117,38 @@ def _mesh_ctx(mesh):
     return ShardCtx(mesh=mesh, rules=rules_for_mesh(mesh))
 
 
+def test_dense_window_scan_fits_one_chip(one_chip):
+    """The executor's one-device dense program: bucket gather from the
+    resident codes, scan, per-query top-n."""
+    from repro.core.distributed import _window_scan_one
+    compiled = _window_scan_one.lower(
+        _spec((N_CODES, 32), jnp.uint8, one_chip),
+        _spec((BUCKET,), jnp.int32, one_chip),
+        _spec((B, 32, 256), jnp.float32, one_chip),
+        _spec((B, BUCKET), jnp.bool_, one_chip),
+        top_n=512, axes=(), n_shards=1).compile()
+    assert _device_bytes(compiled) < HBM_BYTES
+
+
 def test_sharded_window_scan_compiles_on_2x2(mesh):
-    """The dense path on a mesh: the bucket gather from the row-sharded
-    codes, then the per-shard scan and the (dist, id) all-gather."""
+    """The dense path on a mesh: each shard gathers the bucket rows it
+    holds from its own codes, scans, and all-gathers (dist, position)."""
     from repro.core.distributed import sharded_adc_topn_window
-    from repro.core.executor import _row_gather
     ctx = _mesh_ctx(mesh)
-    rows = NamedSharding(mesh, P(ctx.rules.corpus, None))
-    gather = _row_gather(rows).lower(
-        _spec((N_CODES, 32), jnp.uint8, rows),
-        _spec((BUCKET,), jnp.int32, NamedSharding(mesh, P()))).compile()
-    assert _device_bytes(gather) < HBM_BYTES
+    rep = NamedSharding(mesh, P())
     compiled = jax.jit(
-        lambda c, l, k: sharded_adc_topn_window(c, l, k, 512, ctx)).lower(
-        _spec((BUCKET, 32), jnp.uint8, rows),
-        _spec((B, 32, 256), jnp.float32, NamedSharding(mesh, P())),
-        _spec((B, BUCKET), jnp.bool_,
-              NamedSharding(mesh, P(None, ctx.rules.corpus)))).compile()
-    assert "all-gather" in compiled.as_text()
+        lambda c, r, l, k: sharded_adc_topn_window(c, r, l, k, 512, ctx)
+    ).lower(
+        _spec((N_CODES, 32), jnp.uint8,
+              NamedSharding(mesh, P(ctx.rules.corpus, None))),
+        _spec((BUCKET,), jnp.int32, rep),
+        _spec((B, 32, 256), jnp.float32, rep),
+        _spec((B, BUCKET), jnp.bool_, rep)).compile()
+    text = compiled.as_text()
+    assert "all-gather" in text
+    # the codes never move: no collective carries a code row
+    assert "u8[" not in "".join(l for l in text.splitlines()
+                                if "all-gather" in l or "all-reduce" in l)
     assert _device_bytes(compiled) < HBM_BYTES
 
 
