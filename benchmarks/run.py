@@ -7,16 +7,11 @@ previous artifact: a row slower than ``BENCH_REGRESSION_FACTOR`` (default
 1.6x) times its previous value fails the run — the per-PR perf ratchet
 scripts/check.sh's ``kernels`` target enforces in CI.
 
-``--profile DIR`` wraps the selected figures in ``jax.profiler.trace``:
-one TensorBoard-loadable trace (device dispatches + host annotations)
-lands in DIR — see DESIGN.md §11.
-
 ``--selftest`` runs ``scripts/check.sh smoke`` (pytest on the CPU) before
 this process imports JAX: a parent holding the chip would starve the
 child."""
 
 import argparse
-import contextlib
 import importlib
 import json
 import os
@@ -93,11 +88,6 @@ def main() -> None:
                     help="write BENCH_<area>.json per area and fail on "
                          "rows slower than BENCH_REGRESSION_FACTOR "
                          "(default 1.6) x the previous artifact")
-    ap.add_argument("--profile", metavar="DIR",
-                    help="wrap the selected figures in jax.profiler.trace"
-                         "(DIR): one TensorBoard-loadable trace of every "
-                         "device dispatch + host annotation (DESIGN.md "
-                         "§11)")
     args = ap.parse_args()
     if args.selftest:
         root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -120,30 +110,20 @@ def main() -> None:
     print("name,us_per_call,derived")
     ok = True
     regressions = []
-    with contextlib.ExitStack() as stack:
-        if args.profile:
-            import jax
-            os.makedirs(args.profile, exist_ok=True)
-            stack.enter_context(jax.profiler.trace(args.profile))
-            print(f"# jax profiler tracing to {args.profile} "
-                  "(load in TensorBoard)", file=sys.stderr)
-        for name in names:
-            t0 = time.time()
-            try:
-                rows = importlib.import_module(
-                    f"benchmarks.{ALL[name]}").run()
-                for r in rows:
-                    derived = str(r["derived"]).replace(",", ";")
-                    print(f"{r['name']},{r['us_per_call']:.1f},{derived}")
-                if args.persist:
-                    regressions += _persist_and_compare(name, rows, root,
-                                                        factor)
-            except Exception:  # noqa: BLE001
-                ok = False
-                print(f"{name},0,ERROR", file=sys.stdout)
-                traceback.print_exc()
-            print(f"# {name} done in {time.time()-t0:.1f}s",
-                  file=sys.stderr)
+    for name in names:
+        t0 = time.time()
+        try:
+            rows = importlib.import_module(f"benchmarks.{ALL[name]}").run()
+            for r in rows:
+                derived = str(r["derived"]).replace(",", ";")
+                print(f"{r['name']},{r['us_per_call']:.1f},{derived}")
+            if args.persist:
+                regressions += _persist_and_compare(name, rows, root, factor)
+        except Exception:  # noqa: BLE001
+            ok = False
+            print(f"{name},0,ERROR", file=sys.stdout)
+            traceback.print_exc()
+        print(f"# {name} done in {time.time()-t0:.1f}s", file=sys.stderr)
     for msg in regressions:
         print(f"# PERF REGRESSION: {msg}", file=sys.stderr)
     if not ok or regressions:

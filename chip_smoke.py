@@ -128,7 +128,7 @@ def serve(index, queries, clock: CompileClock, label: str, **stack_kw):
     print(f"serve [{label}]: scan-window candidate union mean "
           f"{np.mean(union):.1f}, max {max(union)} (dense bucket "
           f"{1 << int(np.ceil(np.log2(max(max(union), 1))))})")
-    for f in ("t_graph", "t_scan", "t_rerank"):
+    for f in ("t_graph", "t_rerank"):
         mean = np.mean([getattr(r.stats, f) for r in resps])
         print(f"serve [{label}]: mean {f} {mean:.6f} s (host clock, "
               "per query)")

@@ -49,6 +49,14 @@ Per-query accounting is shared: a window of size B attributes ``u = |union|``
 scanned candidates and ``4u/B`` host->device bytes to each member, so
 ``query`` (B=1) and the fused paths report through one ``QueryStats``
 schema.
+
+Stage spans: each window's host stages run inside
+``jax.profiler.TraceAnnotation`` spans, one per window — ``executor.collect``
+(①-③), ``executor.lut`` (④), ``executor.scan`` (⑤'s dispatch),
+``executor.scan_wait`` (the host blocking on ⑤-⑥) and ``executor.rerank``
+(⑦) — each tagged ``batch=`` the ticket's ``batch_id`` and ``window=`` its
+index, so a profiler trace puts the host's stages on the device's clock.
+With no profiler session a span costs about a microsecond.
 """
 
 from __future__ import annotations
@@ -61,6 +69,7 @@ from typing import TYPE_CHECKING, Dict, List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis.concurrency.witness import make_lock
 from repro.core import pq
@@ -99,8 +108,12 @@ class QueryStats:
     rerank_scored: int
     early_stopped: bool
     t_graph: float = 0.0
-    t_scan: float = 0.0
     t_rerank: float = 0.0
+    # thread CPU time over the intervals t_graph / t_rerank time, on the
+    # same thread: wall minus CPU is the stage's wait for the interpreter
+    # lock and the OS
+    cpu_graph: float = 0.0
+    cpu_rerank: float = 0.0
 
 
 @dataclasses.dataclass
@@ -197,9 +210,10 @@ class _Window:
     vals: jax.Array              # (B, tk) masked top-n distances
     pos: jax.Array               # (B, tk) positions into the padded bucket
     t_graph: float
-    t_scan_host: float           # host-side LUT/gather/dispatch time
+    cpu_graph: float             # thread CPU time over t_graph's interval
     start: int = 0               # global index of this window's first query
     wi: int = 0                  # window index within the ticket
+    batch: int = 0               # the ticket's batch_id, for stage spans
     ids_global: bool = False     # fused path: ``pos`` holds physical row ids
     prefilter: int = 0           # union size before the predicate filter
     # the IndexView pinned at dispatch (DESIGN.md §10): candidate
@@ -292,6 +306,8 @@ class QueryExecutor:
         self.ctx = ctx if ctx is not None else ShardCtx()
         self._placed: Optional[jax.Array] = None    # guarded-by: _dispatch_lock
         self._placed_src = None                     # guarded-by: _dispatch_lock
+        # batch and window of the window being dispatched, for its spans
+        self._span_tags = dict(batch=0, window=0)   # guarded-by: _dispatch_lock
         if mesh is not None:
             self.attach_mesh(mesh)
         self._request_tickets: List[BatchTicket] = []   # guarded-by: _backend_lock
@@ -394,78 +410,93 @@ class QueryExecutor:
         return self._placed
 
     # --------------------------------------------------------------- stages
-    def _dispatch(self, queries: np.ndarray,
-                  plans: Sequence[QueryPlan]) -> _Window:  # holds: _dispatch_lock
+    def _dispatch(self, queries: np.ndarray,  # holds: _dispatch_lock
+                  plans: Sequence[QueryPlan]) -> _Window:
         """Stages ①-⑥: host traversal + async device scan for one window.
 
         Heterogeneous per-query plans share the window's scan: traversal
         uses each query's ``top_m``; the scan runs once at the window-max
-        ``top_n`` and each query truncates to its own at merge time."""
-        from repro.core.distributed import sharded_adc_topn_window
-        idx = self.index
+        ``top_n`` and each query truncates to its own at merge time.
+        The stage spans carry ``_span_tags``, set by the caller under the
+        same lock."""
+        span = self._span_tags
         # pin ONE epoch's consistent multi-tier binding for the whole
         # window (DESIGN.md §10): everything below — traversal, gather,
         # scan, and later the re-rank + delta merge — reads this view
-        view = idx.view()
-        t0 = time.perf_counter()
-        # predicate filtering happens HERE, inside candidate collection:
-        # per_q holds only matching ids, so the scan below never spends
-        # ADC work on a row the filter would discard.  The pre-filter
-        # union size rides along as the selectivity witness.
-        pairs = [view.collect_candidates(q, p.top_m, filt=p.filter)
-                 for q, p in zip(queries, plans)]
-        per_q = [p[0] for p in pairs]
-        union = (np.unique(np.concatenate(per_q)).astype(np.int64)
-                 if sum(len(p) for p in per_q) else np.zeros((0,), np.int64))
-        if any(p.filter is not None for p in plans):
-            pre_lists = [p[1] for p in pairs]
-            prefilter = (len(np.unique(np.concatenate(pre_lists)))
-                         if sum(len(p) for p in pre_lists) else 0)
-        else:
-            prefilter = len(union)
-        t1 = time.perf_counter()
-
-        if plans[0].fused:
-            return self._dispatch_fused(queries, plans, per_q, union,
-                                        view=view, t_graph=t1 - t0,
-                                        prefilter=prefilter)
-        u = len(union)
-        bucket = max(64, 1 << int(np.ceil(np.log2(max(u, 1)))))
-        # physical code rows of the union: ids and rows diverge once a
-        # seal-time purge has run (view.row_of maps id -> row; union never
-        # contains a purged id because the tombstone filter ran first)
-        padded = np.zeros(bucket, np.int32)
-        padded[:u] = view.row_of[union]
-        # per-query membership: only a query's own candidates compete in its
-        # top-n (identical semantics at every window size)
-        mask = np.zeros((len(queries), bucket), bool)
-        for qi, ids_q in enumerate(per_q):
-            mask[qi, np.searchsorted(union, ids_q)] = True
-
-        luts = pq.adc_lut_batch(idx.codebook, jnp.asarray(
-            np.stack([idx._lut_query(np.asarray(q, np.float32))
-                      for q in queries])))
-        rows_dev, mask_dev = jnp.asarray(padded), jnp.asarray(mask)
-        if self.ctx.mesh is not None:
-            from repro.core.distributed import replicate_to_mesh
-            # commit every operand to THIS mesh: on a SUB-mesh an
-            # uncommitted one sits on the process default device, which
-            # may belong to a sibling replica's group
-            rows_dev, mask_dev, luts = (replicate_to_mesh(x, self.ctx)
-                                        for x in (rows_dev, mask_dev, luts))
-        scan_top_n = max(p.top_n for p in plans)
-        vals, pos = sharded_adc_topn_window(
-            self._device_codes(view.codes), rows_dev, luts, mask_dev,
-            min(scan_top_n, bucket), self.ctx)
+        view = self.index.view()
+        with TraceAnnotation("executor.collect", **span):
+            t0, c0 = time.perf_counter(), time.thread_time()
+            # predicate filtering happens HERE, inside candidate
+            # collection: per_q holds only matching ids, so the scan below
+            # never spends ADC work on a row the filter would discard.
+            # The pre-filter union size rides along as the selectivity
+            # witness.
+            pairs = [view.collect_candidates(q, p.top_m, filt=p.filter)
+                     for q, p in zip(queries, plans)]
+            per_q = [p[0] for p in pairs]
+            union = (np.unique(np.concatenate(per_q)).astype(np.int64)
+                     if sum(len(p) for p in per_q)
+                     else np.zeros((0,), np.int64))
+            if any(p.filter is not None for p in plans):
+                pre_lists = [p[1] for p in pairs]
+                prefilter = (len(np.unique(np.concatenate(pre_lists)))
+                             if sum(len(p) for p in pre_lists) else 0)
+            else:
+                prefilter = len(union)
+            t1, c1 = time.perf_counter(), time.thread_time()
+        fused = plans[0].fused
+        scan = self._scan_fused if fused else self._scan_dense
+        vals, pos = scan(queries, plans, per_q, union, view, span)
         return _Window(queries=queries, plans=list(plans), per_q=per_q,
                        union=union, vals=vals, pos=pos, t_graph=t1 - t0,
-                       t_scan_host=time.perf_counter() - t1, view=view,
+                       cpu_graph=c1 - c0, ids_global=fused, view=view,
                        prefilter=prefilter)
 
-    def _dispatch_fused(self, queries: np.ndarray,
-                        plans: Sequence[QueryPlan], per_q, union, *,
-                        view, t_graph: float,
-                        prefilter: int = 0) -> _Window:  # holds: _dispatch_lock
+    def _lut_queries(self, queries: np.ndarray) -> np.ndarray:
+        """The window's queries as the LUT build takes them (rotated under
+        OPQ), stacked (B, D)."""
+        return np.stack([self.index._lut_query(np.asarray(q, np.float32))
+                         for q in queries])
+
+    def _scan_dense(self, queries, plans, per_q, union,  # holds: _dispatch_lock
+                    view, span) -> Tuple[jax.Array, jax.Array]:
+        """Stages ④⑤⑥ over the window's candidate union: per-query LUTs,
+        then one masked scan of the union's rows.  Returns the (B, tk)
+        top-n distances and their positions into the padded bucket."""
+        from repro.core.distributed import sharded_adc_topn_window
+        with TraceAnnotation("executor.lut", **span):
+            luts = pq.adc_lut_batch(self.index.codebook,
+                                    jnp.asarray(self._lut_queries(queries)))
+        with TraceAnnotation("executor.scan", **span):
+            u = len(union)
+            bucket = max(64, 1 << int(np.ceil(np.log2(max(u, 1)))))
+            # physical code rows of the union: ids and rows diverge once a
+            # seal-time purge has run (view.row_of maps id -> row; union
+            # never contains a purged id because the tombstone filter ran
+            # first)
+            padded = np.zeros(bucket, np.int32)
+            padded[:u] = view.row_of[union]
+            # per-query membership: only a query's own candidates compete
+            # in its top-n (identical semantics at every window size)
+            mask = np.zeros((len(queries), bucket), bool)
+            for qi, ids_q in enumerate(per_q):
+                mask[qi, np.searchsorted(union, ids_q)] = True
+            rows_dev, mask_dev = jnp.asarray(padded), jnp.asarray(mask)
+            if self.ctx.mesh is not None:
+                from repro.core.distributed import replicate_to_mesh
+                # commit every operand to THIS mesh: on a SUB-mesh an
+                # uncommitted one sits on the process default device,
+                # which may belong to a sibling replica's group
+                rows_dev, mask_dev, luts = (
+                    replicate_to_mesh(x, self.ctx)
+                    for x in (rows_dev, mask_dev, luts))
+            scan_top_n = max(p.top_n for p in plans)
+            return sharded_adc_topn_window(
+                self._device_codes(view.codes), rows_dev, luts, mask_dev,
+                min(scan_top_n, bucket), self.ctx)
+
+    def _scan_fused(self, queries, plans, per_q, union,  # holds: _dispatch_lock
+                    view, span) -> Tuple[jax.Array, jax.Array]:
         """Fused form of stages ④⑤⑥ (``plan.fused``): one LUT→ADC→top-k
         pipeline per shard over per-query candidate ROW LISTS.  No union
         bucket, membership mask, or candidate gather ever materialises —
@@ -475,33 +506,28 @@ class QueryExecutor:
         = |union| so the two paths report through one schema."""
         from repro.core.distributed import (replicate_to_mesh,
                                             sharded_adc_topn_rows)
-        idx = self.index
-        t1 = time.perf_counter()
-        maxlen = max((len(p) for p in per_q), default=0)
-        S = max(64, 1 << int(np.ceil(np.log2(max(maxlen, 1)))))
-        rows = np.full((len(queries), S), -1, np.int32)
-        for qi, ids_q in enumerate(per_q):
-            # candidate ids are np.unique'd => ascending, and row_of is
-            # strictly increasing over live ids, so the physical row lists
-            # stay ascending — pinning top-k tie-breaks to
-            # smallest-row == smallest-id, same as the dense path
-            rows[qi, :len(ids_q)] = view.row_of[ids_q]
-        qrot = jnp.asarray(np.stack(
-            [idx._lut_query(np.asarray(q, np.float32)) for q in queries]))
-        rows_dev = jnp.asarray(rows)
-        codebooks = idx.codebook.codebooks
-        if self.ctx.mesh is not None:
-            qrot = replicate_to_mesh(qrot, self.ctx)
-            rows_dev = replicate_to_mesh(rows_dev, self.ctx)
-            codebooks = replicate_to_mesh(codebooks, self.ctx)
-        scan_top_n = max(p.top_n for p in plans)
-        vals, gids = sharded_adc_topn_rows(
-            self._device_codes(view.codes), qrot, codebooks, rows_dev,
-            min(scan_top_n, S), self.ctx, lut_int8=plans[0].lut_int8)
-        return _Window(queries=queries, plans=list(plans), per_q=per_q,
-                       union=union, vals=vals, pos=gids, t_graph=t_graph,
-                       t_scan_host=time.perf_counter() - t1,
-                       ids_global=True, view=view, prefilter=prefilter)
+        with TraceAnnotation("executor.lut", **span):
+            qrot = jnp.asarray(self._lut_queries(queries))
+        with TraceAnnotation("executor.scan", **span):
+            maxlen = max((len(p) for p in per_q), default=0)
+            S = max(64, 1 << int(np.ceil(np.log2(max(maxlen, 1)))))
+            rows = np.full((len(queries), S), -1, np.int32)
+            for qi, ids_q in enumerate(per_q):
+                # candidate ids are np.unique'd => ascending, and row_of
+                # is strictly increasing over live ids, so the physical
+                # row lists stay ascending — pinning top-k tie-breaks to
+                # smallest-row == smallest-id, same as the dense path
+                rows[qi, :len(ids_q)] = view.row_of[ids_q]
+            rows_dev = jnp.asarray(rows)
+            codebooks = self.index.codebook.codebooks
+            if self.ctx.mesh is not None:
+                qrot = replicate_to_mesh(qrot, self.ctx)
+                rows_dev = replicate_to_mesh(rows_dev, self.ctx)
+                codebooks = replicate_to_mesh(codebooks, self.ctx)
+            scan_top_n = max(p.top_n for p in plans)
+            return sharded_adc_topn_rows(
+                self._device_codes(view.codes), qrot, codebooks, rows_dev,
+                min(scan_top_n, S), self.ctx, lut_int8=plans[0].lut_int8)
 
     def _finish_into(self, w: _Window, futures: Sequence[QueryFuture],
                      deadlines: Sequence[Optional[float]]) -> None:
@@ -510,16 +536,20 @@ class QueryExecutor:
         skip their re-rank; expired deadlines resolve to
         :class:`~repro.core.futures.DeadlineExceeded` instead of starting
         one."""
+        span = dict(batch=w.batch, window=w.wi)
+        with TraceAnnotation("executor.scan_wait", **span):
+            vals = np.asarray(w.vals)      # blocks until the scan lands
+            pos = np.asarray(w.pos)
+        with TraceAnnotation("executor.rerank", **span):
+            self._rerank_window(w, vals, pos, futures, deadlines)
+
+    def _rerank_window(self, w: _Window, vals: np.ndarray, pos: np.ndarray,
+                       futures: Sequence[QueryFuture],
+                       deadlines: Sequence[Optional[float]]) -> None:
+        """Stage ⑦ for each query of a landed window."""
         idx = self.index
         B = len(w.queries)
         u = len(w.union)
-        t0 = time.perf_counter()
-        vals = np.asarray(w.vals)          # blocks until the scan lands
-        pos = np.asarray(w.pos)
-        # host dispatch time + blocking wait: with depth > 1 the gap
-        # between dispatch and finish belongs to the PREVIOUS windows'
-        # rerank, so wall-clock-since-dispatch would double-count it
-        t_scan = w.t_scan_host + (time.perf_counter() - t0)
         for qi, q in enumerate(w.queries):
             fut = futures[w.start + qi]
             if fut.done():                 # cancelled while queued/in flight
@@ -543,7 +573,7 @@ class QueryExecutor:
             order = np.lexsort((ids_sel, d_sel))
             n_eff = min(p.top_n, len(w.per_q[qi]))
             order_ids = ids_sel[order][:n_eff]
-            t2 = time.perf_counter()
+            t2, c2 = time.perf_counter(), time.thread_time()
             q32 = np.asarray(q, np.float32)
             # the SSD tier is row-indexed: purge-surviving rows pack the
             # pages, so the re-rank walks physical rows and the result ids
@@ -572,6 +602,7 @@ class QueryExecutor:
                     sel = np.lexsort((all_ids, all_d))[:p.k]
                     ids_out = all_ids[sel]
                     dists_out = all_d[sel]
+            t3, c3 = time.perf_counter(), time.thread_time()
             stats = QueryStats(
                 ios=rr.io.ios, pages_requested=rr.io.pages_requested,
                 buffer_hits=rr.io.buffer_hits, ssd_bytes=rr.io.bytes_read,
@@ -581,15 +612,15 @@ class QueryExecutor:
                 rerank_batches=rr.batches_run,
                 rerank_scored=rr.candidates_scored,
                 early_stopped=rr.early_stopped,
-                t_graph=w.t_graph / max(B, 1), t_scan=t_scan / max(B, 1),
-                t_rerank=time.perf_counter() - t2)
+                t_graph=w.t_graph / max(B, 1), t_rerank=t3 - t2,
+                cpu_graph=w.cpu_graph / max(B, 1), cpu_rerank=c3 - c2)
             fut._set_result(QueryResult(ids=ids_out, dists=dists_out,
                                         stats=stats))
 
     # --------------------------------------------------------------- submit
     def submit(self, queries, plan: Optional[QueryPlan] = None,
-               overrides: Optional[Sequence[Optional[PlanOverrides]]] = None
-               ):
+               overrides: Optional[Sequence[Optional[PlanOverrides]]] = None,
+               batch_id: Optional[int] = None):
         """Asynchronous entry point: host-traverse + device-dispatch up to
         ``plan.effective_depth()`` windows, then return a
         :class:`~repro.core.futures.BatchTicket` whose per-query futures
@@ -602,7 +633,11 @@ class QueryExecutor:
         Backend-protocol form (DESIGN.md §6): called with a single
         :class:`~repro.serve.client.SearchRequest` instead of a query
         array, returns a :class:`~repro.core.futures.QueryFuture`
-        resolving to a :class:`~repro.serve.client.SearchResponse`."""
+        resolving to a :class:`~repro.serve.client.SearchResponse`.
+
+        ``batch_id`` tags the stage spans of every window; a caller whose
+        own span covers the batch passes its id, else the ticket draws
+        one."""
         from repro.serve.client import SearchRequest
         if isinstance(queries, SearchRequest):
             return self._submit_request(queries)
@@ -616,7 +651,7 @@ class QueryExecutor:
         plans = [plan if overrides is None or overrides[i] is None
                  else overrides[i].merge_into(plan) for i in range(n)]
         futures = [QueryFuture(tag=i) for i in range(n)]
-        ticket = BatchTicket(futures)
+        ticket = BatchTicket(futures, batch_id=batch_id)
         if n == 0:
             return ticket
         t_submit = time.perf_counter()
@@ -651,6 +686,7 @@ class QueryExecutor:
             s = starts[wi]
             try:
                 with self._dispatch_lock:
+                    self._span_tags = dict(batch=ticket.batch_id, window=wi)
                     w = self._dispatch(queries[s:s + W], plans[s:s + W])
             except BaseException as exc:
                 for qi in range(s, min(s + W, n)):
@@ -661,7 +697,7 @@ class QueryExecutor:
                         busy[0] -= 1
                         cond.notify_all()
                 raise
-            w.start, w.wi = s, wi
+            w.start, w.wi, w.batch = s, wi, ticket.batch_id
             with inflight._lock:               # acquires: inflight
                 inflight.commit(w)
                 with cond:                     # acquires: ticket

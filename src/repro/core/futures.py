@@ -39,13 +39,14 @@ query's re-rank would start, never mid-kernel.
 
 from __future__ import annotations
 
+import itertools
 import time
 from typing import Any, Callable, List, Optional, Tuple
 
 from repro.analysis.concurrency.witness import make_condition, make_rlock
 
 __all__ = [
-    "QueryFuture", "BatchTicket",
+    "QueryFuture", "BatchTicket", "next_batch_id",
     "FutureError", "CancelledError", "DeadlineExceeded", "BackpressureError",
 ]
 
@@ -71,6 +72,14 @@ _PENDING, _CANCELLED, _DONE, _ERROR = range(4)
 # bounded condition-variable wait so a caller parked on a future whose
 # producer died still re-checks state (and any caller timeout) regularly
 _WAIT_SLICE_S = 0.05
+
+# process-wide batch identifiers: unique across every executor and replica,
+# so a profiler span names the one batch it belongs to
+_BATCH_IDS = itertools.count()
+
+
+def next_batch_id() -> int:
+    return next(_BATCH_IDS)
 
 
 class QueryFuture:
@@ -245,11 +254,17 @@ class BatchTicket:
     or retired by some thread); the executor's pump/poll closures maintain
     them.  ``wait()`` blocks on ``_cond`` instead of spinning when another
     thread holds the only remaining work.
+
+    ``batch_id`` is unique in the process (``next_batch_id``) and tags the
+    profiler spans of the batch's stages; ``polls`` counts the ``poll()``
+    calls that found the ticket unfinished.
     """
 
     def __init__(self, futures: List[QueryFuture],
-                 events: Optional[List[Tuple[str, int]]] = None):
+                 events: Optional[List[Tuple[str, int]]] = None,
+                 batch_id: Optional[int] = None):
         self.futures = futures
+        self.batch_id = next_batch_id() if batch_id is None else batch_id
         self._lock = make_rlock("ticket")
         self._cond = make_condition("ticket", self._lock)
         self.events: List[Tuple[str, int]] = events if events is not None \
@@ -258,6 +273,7 @@ class BatchTicket:
         self._poll: Callable[[], bool] = lambda: False
         # windows mid-dispatch/mid-retire, any thread
         self._busy = [0]                         # guarded-by: _lock
+        self.polls = 0                           # guarded-by: _lock
 
     def __len__(self) -> int:
         return len(self.futures)
@@ -270,7 +286,16 @@ class BatchTicket:
         already landed (possibly out of order — younger windows may finish
         while an older one is still re-ranking on another thread), and
         dispatch queued windows into freed depth slots.  Returns True if
-        anything advanced."""
+        anything advanced.
+
+        A call on a finished ticket does nothing and is not counted.  The
+        count is taken under ``_lock``, which ``wait()`` takes after the
+        last future resolved, so once ``wait()`` returns ``polls`` is
+        final."""
+        with self._lock:
+            if self.done():
+                return False
+            self.polls += 1
         return self._poll()
 
     def _stall_message(self) -> str:             # holds: _lock

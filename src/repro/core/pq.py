@@ -102,8 +102,15 @@ def adc_lut(cb: PQCodebook, query: jax.Array) -> jax.Array:
 
 
 def adc_lut_batch(cb: PQCodebook, queries: jax.Array) -> jax.Array:
-    """(B, D) -> (B, M, K)."""
-    return jax.vmap(lambda q: adc_lut(cb, q))(queries)
+    """(B, D) -> (B, M, K), as one program (``jit__adc_lut_batch``)."""
+    return _adc_lut_batch(cb.codebooks, queries)
+
+
+@jax.jit
+def _adc_lut_batch(codebooks: jax.Array, queries: jax.Array) -> jax.Array:
+    m, _, dsub = codebooks.shape
+    qs = queries.astype(jnp.float32).reshape(-1, m, 1, dsub)
+    return jnp.sum((codebooks[None] - qs) ** 2, axis=-1)      # (B, M, K)
 
 
 def adc_distances_ref(lut: jax.Array, codes: jax.Array) -> jax.Array:

@@ -55,6 +55,7 @@ from collections import deque
 from typing import Deque, Dict, List, Optional, Tuple
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 from repro.analysis.concurrency.witness import make_condition, make_rlock
 from repro.core.engine import FusionANNSIndex
@@ -62,7 +63,7 @@ from repro.core.engine import FusionANNSIndex
 # QueryStats schema) in PR 5; re-exported here for existing importers
 from repro.core.executor import QUERY_STATS_FIELDS, PlanOverrides
 from repro.core.futures import (BackpressureError, DeadlineExceeded,
-                                FutureError, QueryFuture)
+                                FutureError, QueryFuture, next_batch_id)
 from repro.serve.client import (SearchRequest, SearchResponse,
                                 response_from_result)
 
@@ -412,54 +413,63 @@ class BatchingANNSService:
                         top_n = sug["top_n"]
             overrides.append(PlanOverrides(k=r.k, top_m=top_m, top_n=top_n,
                                            deadline_s=dl, filter=r.filter))
-        ticket = self.executor.submit(queries, plan, overrides=overrides)
-        # propagate cancellations that raced the batch formation
-        for r, f in zip(batch, ticket.futures):
-            if r.future is not None and r.future.cancelled():
-                f.cancel()
-        self._active_ticket = ticket          # ticker may now poll it
-        with self._ticker_cv:
-            self._ticker_cv.notify_all()
-        try:
-            ticket.wait()                     # exceptions stay on the futures
-        finally:
-            self._active_ticket = None
-            events = list(ticket.events)      # stable: wait() barriered
-            with self._lock:
-                self.ticket_events.append(events)
-        t_serve = time.perf_counter() - t0
-        # per-request attribution: shared wall-clock + the executor's
-        # per-query stage timings (res.stats.t_graph/t_scan/t_rerank)
-        responses: List[SearchResponse] = []
-        t_done = time.perf_counter()
-        with self._lock:
-            self.stats["batches"] += 1
-            self.stats["requests"] += len(batch)
-            self.stats["mean_batch"] = (self.stats["requests"]
-                                        / self.stats["batches"])
+        # the batch's span, from submit to its responses; the executor's
+        # stage spans carry the same id, on whichever thread runs them
+        batch_id = next_batch_id()
+        with TraceAnnotation("service.batch", batch=batch_id):
+            ticket = self.executor.submit(queries, plan, overrides=overrides,
+                                          batch_id=batch_id)
+            # propagate cancellations that raced the batch formation
             for r, f in zip(batch, ticket.futures):
-                if f.cancelled():
-                    self.stats["cancelled"] += 1
-                    continue
-                exc = f.exception()
-                if exc is not None:
-                    self.stats["expired"] += isinstance(exc, DeadlineExceeded)
+                if r.future is not None and r.future.cancelled():
+                    f.cancel()
+            self._active_ticket = ticket      # ticker may now poll it
+            with self._ticker_cv:
+                self._ticker_cv.notify_all()
+            try:
+                ticket.wait()             # exceptions stay on the futures
+            finally:
+                self._active_ticket = None
+                events = list(ticket.events)  # stable: wait() barriered
+                polls = ticket.polls          # final once wait() returned
+                with self._lock:
+                    self.ticket_events.append(events)
+            t_serve = time.perf_counter() - t0
+            # per-request attribution: shared wall-clock, the ticker's
+            # polls of the batch, and the executor's per-query stage
+            # timings (res.stats.t_graph/t_rerank, cpu_graph/cpu_rerank)
+            responses: List[SearchResponse] = []
+            t_done = time.perf_counter()
+            with self._lock:
+                self.stats["batches"] += 1
+                self.stats["requests"] += len(batch)
+                self.stats["mean_batch"] = (self.stats["requests"]
+                                            / self.stats["batches"])
+                for r, f in zip(batch, ticket.futures):
+                    if f.cancelled():
+                        self.stats["cancelled"] += 1
+                        continue
+                    exc = f.exception()
+                    if exc is not None:
+                        self.stats["expired"] += isinstance(
+                            exc, DeadlineExceeded)
+                        if r.future is not None:
+                            r.future._set_exception(exc)
+                        continue
+                    res = f.result()
+                    resp = response_from_result(
+                        res, latency_s=t_done - r.t_enqueue, rid=r.rid,
+                        tag=r.tag, tenant=r.tenant,
+                        t_queue_s=t0 - r.t_enqueue, t_serve_s=t_serve,
+                        batch_size=len(batch), ticker_polls=polls)
+                    for field in QUERY_STATS_FIELDS:
+                        self.query_stats[field] += getattr(res.stats, field)
+                    self.query_stats["served"] += 1
                     if r.future is not None:
-                        r.future._set_exception(exc)
-                    continue
-                res = f.result()
-                resp = response_from_result(
-                    res, latency_s=t_done - r.t_enqueue, rid=r.rid,
-                    tag=r.tag, tenant=r.tenant, t_queue_s=t0 - r.t_enqueue,
-                    t_serve_s=t_serve, batch_size=len(batch))
-                for field in QUERY_STATS_FIELDS:
-                    self.query_stats[field] += getattr(res.stats, field)
-                self.query_stats["served"] += 1
-                if r.future is not None:
-                    r.future._set_result(resp)
-                self.latencies_s.append(t_done - r.t_enqueue)
-                self._undrained.append(resp)
-                responses.append(resp)
+                        r.future._set_result(resp)
+                    self.latencies_s.append(t_done - r.t_enqueue)
+                    self._undrained.append(resp)
+                    responses.append(resp)
         # feed the deadline-adaptive resolver OUTSIDE the service lock:
         # its lock is executor-ranked (below service, but observe() also
         # runs a perf-model update that must not serialize submissions).

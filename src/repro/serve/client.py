@@ -93,9 +93,9 @@ class SearchResponse:
 
     ``ids``/``dists``/``stats`` are the query result proper; ``latency_s``
     is submit→resolve wall clock; ``rid``/``tag`` correlate with the
-    request; the ``t_queue_s``/``t_serve_s``/``batch_size`` attribution
-    fields are filled by the batching tiers (a direct executor serve
-    reports ``batch_size=1`` and zero queueing)."""
+    request; the ``t_queue_s``/``t_serve_s``/``batch_size``/``ticker_polls``
+    attribution fields are filled by the batching tiers (a direct executor
+    serve reports ``batch_size=1``, zero queueing and no polls)."""
 
     ids: np.ndarray
     dists: np.ndarray
@@ -107,6 +107,7 @@ class SearchResponse:
     t_queue_s: float = 0.0           # time waiting for the batch window
     t_serve_s: float = 0.0           # batch execution time (shared)
     batch_size: int = 1
+    ticker_polls: int = 0            # the batch's BatchTicket.polls (shared)
 
 
 def as_request(query, k: Optional[int] = None, *,
@@ -136,12 +137,14 @@ def response_from_result(res: QueryResult, *, latency_s: float,
                          rid: int = -1, tag: Any = None,
                          tenant: Optional[str] = None,
                          t_queue_s: float = 0.0, t_serve_s: float = 0.0,
-                         batch_size: int = 1) -> SearchResponse:
+                         batch_size: int = 1,
+                         ticker_polls: int = 0) -> SearchResponse:
     """Wrap an executor :class:`QueryResult` in the uniform response."""
     return SearchResponse(ids=res.ids, dists=res.dists, stats=res.stats,
                           latency_s=latency_s, rid=rid, tag=tag,
                           tenant=tenant, t_queue_s=t_queue_s,
-                          t_serve_s=t_serve_s, batch_size=batch_size)
+                          t_serve_s=t_serve_s, batch_size=batch_size,
+                          ticker_polls=ticker_polls)
 
 
 # ---------------------------------------------------------------------------
