@@ -89,7 +89,7 @@ if TYPE_CHECKING:  # pragma: no cover
 QUERY_STATS_FIELDS = ("ios", "pages_requested", "buffer_hits", "ssd_bytes",
                       "h2d_bytes", "candidates_scanned",
                       "candidates_prefilter", "rerank_batches",
-                      "rerank_scored")
+                      "rerank_scored", "graph_expansions")
 
 
 @dataclasses.dataclass
@@ -107,6 +107,7 @@ class QueryStats:
     rerank_batches: int
     rerank_scored: int
     early_stopped: bool
+    graph_expansions: int = 0    # vertices stage ①'s graph search expanded
     t_graph: float = 0.0
     t_rerank: float = 0.0
     # thread CPU time over the intervals t_graph / t_rerank time, on the
@@ -216,6 +217,8 @@ class _Window:
     batch: int = 0               # the ticket's batch_id, for stage spans
     ids_global: bool = False     # fused path: ``pos`` holds physical row ids
     prefilter: int = 0           # union size before the predicate filter
+    expansions: List[int] = dataclasses.field(default_factory=list)
+    #                              graph vertices each query's search expanded
     # the IndexView pinned at dispatch (DESIGN.md §10): candidate
     # collection, the scan, re-rank, and the delta merge in
     # ``_finish_into`` all read THIS epoch's binding, so a concurrent
@@ -426,13 +429,15 @@ class QueryExecutor:
         view = self.index.view()
         with TraceAnnotation("executor.collect", **span):
             t0, c0 = time.perf_counter(), time.thread_time()
-            # predicate filtering happens HERE, inside candidate
+            # stage ① for the whole window at once: its queries walk the
+            # graph in lockstep, each query's search unchanged.
+            # Predicate filtering happens HERE, inside candidate
             # collection: per_q holds only matching ids, so the scan below
             # never spends ADC work on a row the filter would discard.
             # The pre-filter union size rides along as the selectivity
             # witness.
-            pairs = [view.collect_candidates(q, p.top_m, filt=p.filter)
-                     for q, p in zip(queries, plans)]
+            pairs, expansions = view.collect_window(
+                queries, [p.top_m for p in plans], [p.filter for p in plans])
             per_q = [p[0] for p in pairs]
             union = (np.unique(np.concatenate(per_q)).astype(np.int64)
                      if sum(len(p) for p in per_q)
@@ -450,7 +455,7 @@ class QueryExecutor:
         return _Window(queries=queries, plans=list(plans), per_q=per_q,
                        union=union, vals=vals, pos=pos, t_graph=t1 - t0,
                        cpu_graph=c1 - c0, ids_global=fused, view=view,
-                       prefilter=prefilter)
+                       prefilter=prefilter, expansions=expansions)
 
     def _lut_queries(self, queries: np.ndarray) -> np.ndarray:
         """The window's queries as the LUT build takes them (rotated under
@@ -612,6 +617,7 @@ class QueryExecutor:
                 rerank_batches=rr.batches_run,
                 rerank_scored=rr.candidates_scored,
                 early_stopped=rr.early_stopped,
+                graph_expansions=w.expansions[qi],
                 t_graph=w.t_graph / max(B, 1), t_rerank=t3 - t2,
                 cpu_graph=w.cpu_graph / max(B, 1), cpu_rerank=c3 - c2)
             fut._set_result(QueryResult(ids=ids_out, dists=dists_out,

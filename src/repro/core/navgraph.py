@@ -3,15 +3,17 @@
 SPTAG-flavoured incremental kNN-graph build: vertices are added one by one,
 connected to their current top-R nearest, and neighbours back-update under a
 max-degree cap.  Search is best-first beam search (the CPU stage ② of the
-online pipeline).  A device-side ``lax.while_loop`` variant exists for
-completeness (tests prove it matches), but production placement is CPU,
-exactly as in the paper."""
+online pipeline), run for a scan window's queries in lockstep so that each
+step scores all their new neighbours in one numpy call.  A device-side
+``lax.while_loop`` variant exists for completeness (tests prove it
+matches), but production placement is CPU, exactly as in the paper."""
 
 from __future__ import annotations
 
 import dataclasses
+import functools
 import heapq
-from typing import List, Optional, Tuple
+from typing import List, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -33,17 +35,39 @@ class NavGraph:
 
     def seed_beam(self, query: np.ndarray, n_super: int = 3,
                   per_super: int = 3) -> np.ndarray:
+        """``seed_beams`` of one query."""
+        return self.seed_beams(query[None], n_super, per_super)[0]
+
+    def seed_beams(self, queries: np.ndarray, n_super: int = 3,
+                   per_super: int = 3) -> List[np.ndarray]:
+        """Seed vertices of each row of ``queries`` (B, D): the entry plus
+        the ``per_super`` nearest members of each of the ``n_super``
+        nearest super-centroids, ascending."""
         if self.super_centroids is None:
-            return np.array([self.entry], np.int64)
-        ds = np.sum((self.super_centroids - query) ** 2, -1)
-        out = [np.array([self.entry], np.int64)]
-        for s in np.argsort(ds)[:n_super]:
-            members = np.where(self.super_assign == s)[0]
-            if not len(members):
-                continue
-            dm = np.sum((self.points[members] - query) ** 2, -1)
-            out.append(members[np.argsort(dm)[:per_super]])
-        return np.unique(np.concatenate(out))
+            return [np.array([self.entry], np.int64)] * len(queries)
+        order, bounds = self._by_super
+        ds = np.sum((self.super_centroids[None] - queries[:, None]) ** 2, -1)
+        out = []
+        for q, row in zip(queries, np.argsort(ds, -1)[:, :n_super].tolist()):
+            seeds = [np.array([self.entry], np.int64)]
+            for s in row:
+                lo, hi = bounds[s], bounds[s + 1]
+                if lo == hi:
+                    continue
+                d = np.sum((self.points[order[lo:hi]] - q) ** 2, -1)
+                seeds.append(order[lo + np.argsort(d)[:per_super]])
+            out.append(np.unique(np.concatenate(seeds)))
+        return out
+
+    @functools.cached_property
+    def _by_super(self) -> Tuple[np.ndarray, List[int]]:
+        """Vertices ordered by super-centroid, ascending within each (as
+        ``np.where(super_assign == s)`` lists them), and each
+        super-centroid's bounds in that order."""
+        order = np.argsort(self.super_assign, kind="stable")
+        counts = np.bincount(self.super_assign,
+                             minlength=len(self.super_centroids))
+        return order, [0] + np.cumsum(counts).tolist()
 
 
 def _seed_tree(points: np.ndarray):
@@ -156,33 +180,71 @@ def search(graph: NavGraph, query: np.ndarray, top_m: int,
            ef: Optional[int] = None) -> np.ndarray:
     """CPU best-first beam search -> ids of the top-m nearest centroids
     (online stage ②).  ef defaults to 2*top_m."""
-    ef = ef or max(2 * top_m, 32)
+    return search_batch(graph, query[None], [top_m], ef)[0][0]
+
+
+def search_batch(graph: NavGraph, queries: np.ndarray,
+                 top_ms: Sequence[int], ef: Optional[int] = None
+                 ) -> Tuple[List[np.ndarray], List[int]]:
+    """``search`` for each row of ``queries`` (B, D), the B searches run in
+    lockstep -> (each query's ids, the vertices each query expanded).
+    Each step, every live query pops its own best candidate; one gather
+    and one row-wise distance reduction then score the unvisited
+    neighbours of all popped vertices, and each query's heaps take them in
+    neighbour order.  Per query that is the same search, pop for pop, with
+    the same float32 distances."""
     points, neighbors = graph.points, graph.neighbors
-    visited = np.zeros(len(points), bool)
-    cand: List[Tuple[float, int]] = []
-    best: List[Tuple[float, int]] = []
-    for entry in graph.seed_beam(query):
-        entry = int(entry)
-        visited[entry] = True
-        d0 = float(np.sum((points[entry] - query) ** 2))
-        heapq.heappush(cand, (d0, entry))
-        heapq.heappush(best, (-d0, entry))
-    while cand:
-        dist, u = heapq.heappop(cand)
-        if len(best) >= ef and dist > -best[0][0]:
-            break
-        for v in neighbors[u]:
-            if v < 0 or visited[v]:
+    b = len(queries)
+    efs = [ef or max(2 * m, 32) for m in top_ms]
+    visited = [bytearray(len(points)) for _ in range(b)]
+    cand: List[List[Tuple[float, int]]] = [[] for _ in range(b)]
+    best: List[List[Tuple[float, int]]] = [[] for _ in range(b)]
+    seeds = graph.seed_beams(queries)
+    owner = np.repeat(np.arange(b), [len(s) for s in seeds])
+    ids = np.concatenate(seeds)
+    d0 = np.sum((points[ids] - queries[owner]) ** 2, -1)
+    for qi, u, d in zip(owner.tolist(), ids.tolist(), d0.tolist()):
+        visited[qi][u] = 1
+        heapq.heappush(cand[qi], (d, u))
+        heapq.heappush(best[qi], (-d, u))
+    n_exp = [0] * b
+    live = list(range(b))
+    while live:
+        # pop each live query's best candidate and gather its unvisited
+        # neighbours, in neighbour order
+        expanding, vs, vo = [], [], []
+        for qi in live:
+            if not cand[qi]:
                 continue
-            visited[v] = True
-            dv = float(np.sum((points[v] - query) ** 2))
-            if len(best) < ef or dv < -best[0][0]:
-                heapq.heappush(cand, (dv, v))
-                heapq.heappush(best, (-dv, v))
-                if len(best) > ef:
-                    heapq.heappop(best)
-    out = sorted(((-nd, v) for nd, v in best))
-    return np.array([v for _, v in out[:top_m]], np.int32)
+            dist, u = heapq.heappop(cand[qi])
+            if len(best[qi]) >= efs[qi] and dist > -best[qi][0][0]:
+                continue
+            n_exp[qi] += 1
+            expanding.append(qi)
+            seen = visited[qi]
+            for v in neighbors[u].tolist():
+                if v >= 0 and not seen[v]:
+                    seen[v] = 1
+                    vs.append(v)
+                    vo.append(qi)
+        live = expanding
+        if not vs:
+            continue
+        dv = np.sum((points[vs] - queries[vo]) ** 2, -1).tolist()
+        last = -1
+        for qi, v, d in zip(vo, vs, dv):
+            if qi != last:
+                bq, cq, e, last = best[qi], cand[qi], efs[qi], qi
+            if len(bq) < e or d < -bq[0][0]:
+                heapq.heappush(cq, (d, v))
+                heapq.heappush(bq, (-d, v))
+                if len(bq) > e:
+                    heapq.heappop(bq)
+    out = []
+    for qi in range(b):
+        ranked = sorted((-nd, v) for nd, v in best[qi])
+        out.append(np.array([v for _, v in ranked[:top_ms[qi]]], np.int32))
+    return out, n_exp
 
 
 def search_jax(points: jax.Array, neighbors: jax.Array, entry: int,

@@ -30,7 +30,7 @@ from __future__ import annotations
 
 import dataclasses
 import threading
-from typing import TYPE_CHECKING, Optional, Tuple
+from typing import TYPE_CHECKING, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -209,16 +209,34 @@ class IndexView:
         return self.collect_candidates(query, top_m, dedup=dedup,
                                        filt=filt)[0]
 
+    def collect_window(self, queries: np.ndarray, top_ms: Sequence[int],
+                       filts: Sequence[Optional[Predicate]]
+                       ) -> Tuple[List[Tuple[np.ndarray, np.ndarray]],
+                                  List[int]]:
+        """``collect_candidates`` for each row of ``queries`` (B, D), their
+        graph searches run in lockstep (``navgraph.search_batch``) -> (each
+        query's ``(filtered_ids, prefilter_ids)``, the graph vertices each
+        query's search expanded)."""
+        cids, expansions = ng.search_batch(
+            self.graph, np.asarray(queries, np.float32), top_ms)
+        pairs = [self.collect_candidates(q, m, filt=f, cids=c)
+                 for q, m, f, c in zip(queries, top_ms, filts, cids)]
+        return pairs, expansions
+
     def collect_candidates(self, query: np.ndarray, top_m: int,
                            dedup: bool = True,
-                           filt: Optional[Predicate] = None
+                           filt: Optional[Predicate] = None,
+                           cids: Optional[np.ndarray] = None
                            ) -> Tuple[np.ndarray, np.ndarray]:
         """``(filtered_ids, prefilter_ids)`` — the second array is the
         candidate set BEFORE the predicate (after dedup + tombstones), so
         callers can prove selectivity shrank the scan
         (``QueryStats.candidates_prefilter``).  Same object twice when
-        ``filt is None``."""
-        cids = ng.search(self.graph, query.astype(np.float32), top_m)
+        ``filt is None``.  ``cids``: the query's top-m centroid ids, where
+        ``collect_window`` has searched the graph already; None searches
+        here."""
+        if cids is None:
+            cids = ng.search(self.graph, query.astype(np.float32), top_m)
         rows = np.concatenate([self.posting.members[c] for c in cids]) \
             if len(cids) else np.zeros((0,), np.int32)
         if dedup:

@@ -142,3 +142,43 @@ def sharded_results():
 @pytest.mark.parametrize("key", ["ids_exact", "dists_exact", "single_exact"])
 def test_sharded_scan_matches_single_device(sharded_results, key):
     assert sharded_results[key], sharded_results
+
+
+# ---------------------------------------------------------------------------
+# Stage ①: a window's queries search the graph in lockstep
+# ---------------------------------------------------------------------------
+
+@pytest.fixture(scope="module")
+def attributed(anns_bundle):
+    from repro.core.engine import FusionANNSIndex
+    b = anns_bundle
+    return FusionANNSIndex.build(
+        b.data, b.cfg, attributes={"cat": np.arange(len(b.data)) % 4})
+
+
+def test_lockstep_window_collects_what_each_query_collects_alone(
+        anns_bundle, attributed):
+    from test_navgraph import oracle_search
+    from repro.core.executor import PlanOverrides
+    from repro.core.filters import Eq
+    queries = np.asarray(anns_bundle.queries[:8], np.float32)
+    overrides = [PlanOverrides(top_m=m, filter=Eq("cat", 1) if i == 5
+                               else None)
+                 for i, m in enumerate([16, 4, 24, 8, 16, 12, 2, 30])]
+    ex = attributed.executor
+    plans = [o.merge_into(attributed.plan(window=8)) for o in overrides]
+    with ex._dispatch_lock:
+        w = ex._dispatch(queries, plans)
+    for q, p, ids in zip(queries, plans, w.per_q):
+        want, _ = w.view.collect_candidates(q, p.top_m, filt=p.filter)
+        np.testing.assert_array_equal(ids, want)
+    assert 0 < len(w.per_q[5]) < len(w.view.collect_candidates(
+        queries[5], plans[5].top_m)[0])              # the filter bit
+    answers = {window: ex.submit(queries, attributed.plan(window=window),
+                                 overrides=overrides).results()
+               for window in (1, 8)}
+    for q, p, one, eight in zip(queries, plans, answers[1], answers[8]):
+        np.testing.assert_array_equal(one.ids, eight.ids)
+        _, want_n = oracle_search(attributed.graph, q, p.top_m)
+        assert one.stats.graph_expansions == eight.stats.graph_expansions \
+            == want_n > 0

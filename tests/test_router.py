@@ -58,9 +58,11 @@ def test_policy_parity_with_single_replica_run(anns_bundle, policy):
     roll = router.stats_rollup()
     assert sum(roll["routed"]) == len(b.queries)
     assert roll["requests"] == len(b.queries)
-    # the QueryStats rollup saw every request's re-rank traffic
+    # the QueryStats rollup saw every request's re-rank traffic and
+    # graph search
     assert roll["query_stats"]["ios"] > 0
     assert roll["query_stats"]["rerank_scored"] > 0
+    assert roll["query_stats"]["graph_expansions"] >= len(b.queries)
 
 
 def test_round_robin_spreads_evenly(anns_bundle):
