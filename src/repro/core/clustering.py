@@ -87,10 +87,14 @@ _ROWS = 16384
 def _nearest(blk: jax.Array, centers: jax.Array, r: int):
     """The ``r`` nearest centroids of each row: (squared distances, ids),
     both (rows, r), ascending.  Full f32 matmul precision: the squared
-    distances subtract terms ~|x|^2 apart, which bf16 passes would swamp."""
+    distances subtract terms ~|x|^2 apart, which bf16 passes would swamp.
+    They are clamped at 0: a row that is its own centroid (a cluster of
+    one) reads a little below 0 after the subtraction, and Eq. 2's
+    ``d <= (1+eps)^2 * d_1`` then kept it out of every list."""
     d2 = (jnp.sum(blk * blk, -1)[:, None]
           - 2.0 * jnp.dot(blk, centers.T, precision=jax.lax.Precision.HIGHEST)
           + jnp.sum(centers * centers, -1)[None])
+    d2 = jnp.maximum(d2, 0.0)
     neg, idx = jax.lax.top_k(-d2, r)
     return -neg, idx
 
@@ -169,5 +173,16 @@ def assign_with_replication(data: np.ndarray, centroids: np.ndarray,
 def build_posting_lists(rng: np.random.Generator, data: np.ndarray,
                         n_clusters: int, eps: float = 0.10,
                         max_replicas: int = 8) -> PostingLists:
+    """Clusters ``data`` and assigns it with replication, then drops every
+    centroid whose list came out empty, so no query's ``top_m`` holds a
+    list with nothing in it.  The kept lists are renumbered densely in
+    their old order, and ``primary`` with them; a vector's primary list
+    always holds it, so no primary is dropped."""
     cents = hierarchical_balanced_clustering(rng, data, n_clusters)
-    return assign_with_replication(data, cents, eps, max_replicas)
+    pl = assign_with_replication(data, cents, eps, max_replicas)
+    keep = np.array([len(m) > 0 for m in pl.members])
+    new_id = np.cumsum(keep, dtype=np.int32) - 1
+    return PostingLists(
+        centroids=pl.centroids[keep],
+        members=[m for m, k in zip(pl.members, keep) if k],
+        primary=new_id[pl.primary])

@@ -89,7 +89,8 @@ if TYPE_CHECKING:  # pragma: no cover
 QUERY_STATS_FIELDS = ("ios", "pages_requested", "buffer_hits", "ssd_bytes",
                       "h2d_bytes", "candidates_scanned",
                       "candidates_prefilter", "rerank_batches",
-                      "rerank_scored", "graph_expansions")
+                      "rerank_scored", "graph_expansions", "lists_empty",
+                      "candidates_collected")
 
 
 @dataclasses.dataclass
@@ -108,6 +109,10 @@ class QueryStats:
     rerank_scored: int
     early_stopped: bool
     graph_expansions: int = 0    # vertices stage ①'s graph search expanded
+    lists_empty: int = 0         # of the query's top_m lists, those that
+    #                              held no live row
+    candidates_collected: int = 0  # the query's own candidates after dedup
+    #                              and tombstones, before any predicate
     t_graph: float = 0.0
     t_rerank: float = 0.0
     # thread CPU time over the intervals t_graph / t_rerank time, on the
@@ -219,6 +224,10 @@ class _Window:
     prefilter: int = 0           # union size before the predicate filter
     expansions: List[int] = dataclasses.field(default_factory=list)
     #                              graph vertices each query's search expanded
+    lists_empty: List[int] = dataclasses.field(default_factory=list)
+    collected: List[int] = dataclasses.field(default_factory=list)
+    #                              each query's lists with no live row, and
+    #                              its candidates before the predicate
     # the IndexView pinned at dispatch (DESIGN.md §10): candidate
     # collection, the scan, re-rank, and the delta merge in
     # ``_finish_into`` all read THIS epoch's binding, so a concurrent
@@ -436,7 +445,7 @@ class QueryExecutor:
             # never spends ADC work on a row the filter would discard.
             # The pre-filter union size rides along as the selectivity
             # witness.
-            pairs, expansions = view.collect_window(
+            pairs, expansions, lists_empty = view.collect_window(
                 queries, [p.top_m for p in plans], [p.filter for p in plans])
             per_q = [p[0] for p in pairs]
             union = (np.unique(np.concatenate(per_q)).astype(np.int64)
@@ -455,7 +464,9 @@ class QueryExecutor:
         return _Window(queries=queries, plans=list(plans), per_q=per_q,
                        union=union, vals=vals, pos=pos, t_graph=t1 - t0,
                        cpu_graph=c1 - c0, ids_global=fused, view=view,
-                       prefilter=prefilter, expansions=expansions)
+                       prefilter=prefilter, expansions=expansions,
+                       lists_empty=lists_empty,
+                       collected=[len(p[1]) for p in pairs])
 
     def _lut_queries(self, queries: np.ndarray) -> np.ndarray:
         """The window's queries as the LUT build takes them (rotated under
@@ -618,6 +629,8 @@ class QueryExecutor:
                 rerank_scored=rr.candidates_scored,
                 early_stopped=rr.early_stopped,
                 graph_expansions=w.expansions[qi],
+                lists_empty=w.lists_empty[qi],
+                candidates_collected=w.collected[qi],
                 t_graph=w.t_graph / max(B, 1), t_rerank=t3 - t2,
                 cpu_graph=w.cpu_graph / max(B, 1), cpu_rerank=c3 - c2)
             fut._set_result(QueryResult(ids=ids_out, dists=dists_out,

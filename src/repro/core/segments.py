@@ -212,16 +212,31 @@ class IndexView:
     def collect_window(self, queries: np.ndarray, top_ms: Sequence[int],
                        filts: Sequence[Optional[Predicate]]
                        ) -> Tuple[List[Tuple[np.ndarray, np.ndarray]],
-                                  List[int]]:
+                                  List[int], List[int]]:
         """``collect_candidates`` for each row of ``queries`` (B, D), their
         graph searches run in lockstep (``navgraph.search_batch``) -> (each
         query's ``(filtered_ids, prefilter_ids)``, the graph vertices each
-        query's search expanded)."""
+        query's search expanded, how many of each query's lists held no
+        live row)."""
         cids, expansions = ng.search_batch(
             self.graph, np.asarray(queries, np.float32), top_ms)
         pairs = [self.collect_candidates(q, m, filt=f, cids=c)
                  for q, m, f, c in zip(queries, top_ms, filts, cids)]
-        return pairs, expansions
+        return pairs, expansions, self.lists_empty(cids)
+
+    def lists_empty(self, cids: Sequence[np.ndarray]) -> List[int]:
+        """How many of each query's posting lists ``cids[i]`` hold no live
+        row (no row at all, or only tombstoned ones).  One count over the
+        whole window: each live row is credited to its list, and each list
+        with none to its query."""
+        lists = [self.posting.members[c] for cq in cids for c in cq]
+        if not lists:
+            return [0] * len(cids)
+        slot = np.repeat(np.arange(len(lists)), [len(m) for m in lists])
+        live = ~self.tombstones[self.id_of[np.concatenate(lists)]]
+        dead = np.bincount(slot[live], minlength=len(lists)) == 0
+        query = np.repeat(np.arange(len(cids)), [len(c) for c in cids])
+        return np.bincount(query[dead], minlength=len(cids)).tolist()
 
     def collect_candidates(self, query: np.ndarray, top_m: int,
                            dedup: bool = True,

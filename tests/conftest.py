@@ -25,7 +25,7 @@ import sys
 os.environ.pop("XLA_FLAGS", None)
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
-sys.path.insert(0, os.path.dirname(__file__))      # for _propshim
+sys.path.insert(0, os.path.dirname(__file__))      # for _propshim, _plant
 
 import numpy as np
 import pytest

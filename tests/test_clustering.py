@@ -2,6 +2,7 @@
 
 import numpy as np
 import pytest
+from _plant import planting
 from _propshim import given, settings, strategies as st
 
 from repro.core import clustering
@@ -68,3 +69,40 @@ def test_balanced_clustering_properties(n, k, seed):
     cents = clustering.hierarchical_balanced_clustering(rng, data, k)
     assert cents.shape == (k, 8)
     assert np.isfinite(cents).all()
+
+
+# ---------------------------------------------------------------- empty lists
+
+@pytest.mark.parametrize("far", [True, False])
+def test_build_drops_empty_lists_and_renumbers(monkeypatch, far):
+    rng = np.random.default_rng(0)
+    data = clustered_vectors(rng, 2000, 64, n_clusters=20)
+    planted = planting(6, far)
+    monkeypatch.setattr(clustering, "hierarchical_balanced_clustering",
+                        planted)
+    pl = clustering.build_posting_lists(np.random.default_rng(0), data,
+                                        n_clusters=60, eps=0.15)
+    raw = clustering.assign_with_replication(data, planted.centroids,
+                                             eps=0.15)
+    raw_sizes = np.array([len(m) for m in raw.members])
+    assert (raw_sizes == 0).sum() >= 6            # the plant took
+    kept = np.flatnonzero(raw_sizes > 0)
+    assert pl.n_clusters == len(kept)
+    assert all(len(m) for m in pl.members)
+    np.testing.assert_array_equal(pl.centroids, planted.centroids[kept])
+    for new, old in enumerate(kept):               # members, renumbered
+        np.testing.assert_array_equal(pl.members[new], raw.members[old])
+    np.testing.assert_array_equal(kept[pl.primary], raw.primary)
+    for i in range(len(data)):
+        assert i in pl.members[pl.primary[i]]
+
+
+def test_a_row_that_is_its_own_centroid_joins_its_list():
+    """A cluster of one: its squared distance reads a little below 0 after
+    cancellation, and Eq. 2 must still keep the row in its own list."""
+    rng = np.random.default_rng(3)
+    data = (10.0 * rng.standard_normal((600, 32))).astype(np.float32)
+    pl = clustering.assign_with_replication(data, data[:40].copy())
+    for i in range(40):
+        assert i in pl.members[pl.primary[i]]
+        assert pl.primary[i] == i

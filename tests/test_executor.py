@@ -169,9 +169,10 @@ def test_lockstep_window_collects_what_each_query_collects_alone(
     plans = [o.merge_into(attributed.plan(window=8)) for o in overrides]
     with ex._dispatch_lock:
         w = ex._dispatch(queries, plans)
-    for q, p, ids in zip(queries, plans, w.per_q):
-        want, _ = w.view.collect_candidates(q, p.top_m, filt=p.filter)
+    for q, p, ids, n in zip(queries, plans, w.per_q, w.collected):
+        want, pre = w.view.collect_candidates(q, p.top_m, filt=p.filter)
         np.testing.assert_array_equal(ids, want)
+        assert n == len(pre)                     # before the predicate
     assert 0 < len(w.per_q[5]) < len(w.view.collect_candidates(
         queries[5], plans[5].top_m)[0])              # the filter bit
     answers = {window: ex.submit(queries, attributed.plan(window=window),
@@ -182,3 +183,69 @@ def test_lockstep_window_collects_what_each_query_collects_alone(
         _, want_n = oracle_search(attributed.graph, q, p.top_m)
         assert one.stats.graph_expansions == eight.stats.graph_expansions \
             == want_n > 0
+
+
+# ------------------------------------------------- collection counters
+
+def _lists_and_rows(view, q, top_m):
+    from repro.core import navgraph as ng
+    cids = ng.search(view.graph, np.asarray(q, np.float32), top_m)
+    return cids, [view.posting.members[c] for c in cids]
+
+
+def test_collection_counters_are_exact(fresh_index, anns_bundle):
+    """``lists_empty`` and ``candidates_collected`` against a count made
+    here: on the built index (no list empty), then on a view with one of
+    a query's lists emptied and another one's rows all tombstoned."""
+    import dataclasses
+    from repro.core.clustering import PostingLists
+    ix, queries = fresh_index, anns_bundle.queries
+    top_m = ix.cfg.top_m
+    for q, r in zip(queries, ix.batch_query(queries)):
+        _, lists = _lists_and_rows(ix.view(), q, top_m)
+        assert r.stats.lists_empty == 0
+        assert r.stats.candidates_collected == len(
+            np.unique(np.concatenate(lists)))
+
+    q = queries[0]
+    view = ix.view()
+    cids, lists = _lists_and_rows(view, q, top_m)
+    members = list(view.posting.members)
+    members[cids[1]] = members[cids[1]][:0]
+    ix._view = dataclasses.replace(view, posting=PostingLists(
+        view.posting.centroids, members, view.posting.primary))
+    ix.delete(members[cids[0]])
+    tomb = ix.view().tombstones
+
+    def want(q):
+        cids, _ = _lists_and_rows(ix.view(), q, top_m)
+        rows = np.unique(np.concatenate([members[c] for c in cids]))
+        return (sum(not np.any(~tomb[members[c]]) for c in cids),
+                int((~tomb[rows]).sum()))
+    assert want(q)[0] >= 2
+    wants = [want(x) for x in queries[:4]]
+    for rs in ([ix.query(q)], ix.batch_query(queries[:4]),
+               ix.query_batch_fused(queries[:4])):
+        for r, (empty, collected) in zip(rs, wants):
+            assert r.stats.lists_empty == empty
+            assert r.stats.candidates_collected == collected
+
+
+def test_rollups_sum_the_collection_counters(anns_bundle):
+    from repro.serve.anns_service import BatchingANNSService
+    from repro.serve.client import SearchRequest
+    from repro.serve.router import ReplicaRouter
+    b = anns_bundle
+    for backend in (BatchingANNSService(b.index, max_batch=4,
+                                        max_wait_s=0.0),
+                    ReplicaRouter(b.index, n_replicas=2,
+                                  policy="round_robin", threaded=False,
+                                  max_batch=4, max_wait_s=0.0)):
+        futs = [backend.submit(SearchRequest(query=q)) for q in b.queries]
+        backend.drain()
+        stats = [f.result().stats for f in futs]
+        roll = backend.stats_rollup()["query_stats"]
+        for field in ("lists_empty", "candidates_collected"):
+            assert roll[field] == sum(getattr(s, field) for s in stats)
+        assert roll["candidates_collected"] >= len(b.queries) * 10
+        backend.stop()
