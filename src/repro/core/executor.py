@@ -20,7 +20,8 @@ list, parameterized only by the batch window:
   ⑥ top-n merge      global merge of shard-local top-ns + host-side
                      (distance, id) lexicographic ordering, so sharded and
                      single-device scans return bit-identical rankings
-  ⑦ heuristic rerank Algorithm 1 against the SSD tier (host)
+  ⑦ heuristic rerank Algorithm 1 against the SSD tier (host), in lockstep
+                     over the window's queries that share its knobs
 
 Tier placement (unchanged from engine.py): navigation graph + posting-list
 IDs in host numpy ("DRAM"); PQ codes + codebooks in jax arrays ("HBM",
@@ -113,6 +114,9 @@ class QueryStats:
     #                              held no live row
     candidates_collected: int = 0  # the query's own candidates after dedup
     #                              and tombstones, before any predicate
+    rerank_width: float = 1.0    # Σ batches_run ÷ lockstep steps of the
+    #                              query's re-rank group: the mean number
+    #                              of queries scored together at a step
     t_graph: float = 0.0
     t_rerank: float = 0.0
     # thread CPU time over the intervals t_graph / t_rerank time, on the
@@ -562,79 +566,110 @@ class QueryExecutor:
     def _rerank_window(self, w: _Window, vals: np.ndarray, pos: np.ndarray,
                        futures: Sequence[QueryFuture],
                        deadlines: Sequence[Optional[float]]) -> None:
-        """Stage ⑦ for each query of a landed window."""
-        idx = self.index
-        B = len(w.queries)
-        u = len(w.union)
-        for qi, q in enumerate(w.queries):
+        """Stage ⑦ for a landed window: the queries that share Algorithm 1's
+        knobs re-rank in lockstep, one ``heuristic_rerank`` call per group;
+        the delta merge stays per query.  Cancellation and deadlines are
+        checked once, as the window's re-rank starts."""
+        now = time.perf_counter()
+        groups: Dict[tuple, List[int]] = {}
+        for qi, p in enumerate(w.plans):
             fut = futures[w.start + qi]
             if fut.done():                 # cancelled while queued/in flight
                 continue
             dl = deadlines[w.start + qi]
-            if dl is not None and time.perf_counter() > dl:
+            if dl is not None and now > dl:
                 fut._set_exception(DeadlineExceeded(
                     f"deadline passed before re-rank of query "
                     f"{w.start + qi}"))
                 continue
-            p = w.plans[qi]
-            good = np.isfinite(vals[qi])
-            # fused windows return physical code rows directly (mapped
-            # back to global ids through the pinned view); dense windows
-            # return positions into the padded candidate bucket, whose
-            # backing ``union`` already holds global ids
-            ids_sel = (w.view.id_of[pos[qi][good]] if w.ids_global
-                       else w.union[pos[qi][good]])
-            d_sel = vals[qi][good]
-            # ascending (distance, id): makes sharded == unsharded exactly
-            order = np.lexsort((ids_sel, d_sel))
-            n_eff = min(p.top_n, len(w.per_q[qi]))
-            order_ids = ids_sel[order][:n_eff]
-            t2, c2 = time.perf_counter(), time.thread_time()
-            q32 = np.asarray(q, np.float32)
-            # the SSD tier is row-indexed: purge-surviving rows pack the
-            # pages, so the re-rank walks physical rows and the result ids
-            # map back through id_of (monotone — ordering is unchanged)
-            rr = heuristic_rerank(
-                q32, w.view.row_of[order_ids], idx.ssd, p.k,
-                batch_size=p.rerank_batch, eps=p.rerank_eps,
-                beta=p.rerank_beta,
-                disable_early_stop=p.disable_early_stop)
-            rr_ids = w.view.id_of[rr.ids] if len(rr.ids) else \
-                rr.ids.astype(np.int64)
-            ids_out, dists_out = rr_ids, rr.dists
+            groups.setdefault((p.k, p.rerank_batch, p.rerank_eps,
+                               p.rerank_beta, p.disable_early_stop),
+                              []).append(qi)
+        if not groups:
+            return
+        # each query's candidates in ascending (distance, id) order — what
+        # makes sharded == unsharded exactly — sorted for the whole window
+        # at once: a slot the scan left empty (inf) sorts last.  Fused
+        # windows return physical code rows directly (mapped back to global
+        # ids through the pinned view); dense windows return positions into
+        # the padded candidate bucket, whose backing ``union`` holds ids.
+        good = np.isfinite(vals)
+        at = np.where(good, pos, 0)
+        ids = (w.view.id_of[at] if w.ids_global
+               else w.union[at] if len(w.union)
+               else np.zeros(at.shape, np.int64))
+        ids = np.take_along_axis(ids, np.lexsort((ids, vals)), 1)
+        n_good = good.sum(1)
+        for members in groups.values():
+            self._rerank_group(w, members, ids, n_good, futures)
+
+    def _rerank_group(self, w: _Window, members: List[int], ids: np.ndarray,
+                      n_good: np.ndarray,
+                      futures: Sequence[QueryFuture]) -> None:
+        """Algorithm 1 in lockstep over ``members`` (queries of one plan's
+        knobs), then each one's delta merge; the group's time is shared
+        evenly among its queries, as ``t_graph`` is over the window."""
+        p = w.plans[members[0]]
+        t0, c0 = time.perf_counter(), time.thread_time()
+        # the SSD tier is row-indexed: purge-surviving rows pack the pages,
+        # so the re-rank walks physical rows and the result ids map back
+        # through id_of (monotone — ordering is unchanged)
+        rows = [w.view.row_of[ids[qi, :min(w.plans[qi].top_n,
+                                           len(w.per_q[qi]), n_good[qi])]]
+                for qi in members]
+        rr = heuristic_rerank(
+            np.asarray(w.queries[members], np.float32), rows, self.index.ssd,
+            p.k, batch_size=p.rerank_batch, eps=p.rerank_eps,
+            beta=p.rerank_beta, disable_early_stop=p.disable_early_stop)
+        steps = int(rr.batches_run.max(initial=0))
+        width = float(rr.batches_run.sum()) / steps if steps else 1.0
+        answers = []
+        for j, qi in enumerate(members):
+            n = min(p.k, int(rr.candidates_scored[j]))
+            rr_ids = w.view.id_of[rr.ids[j, :n]]
+            ids_out, dists_out = rr_ids, rr.dists[j, :n]
             # delta merge (DESIGN.md §10): the pinned view's unsealed rows
             # are scanned exactly (under the SAME predicate) and merged on
             # (dist, id) — both streams are exact squared-L2, and delta
             # ids (>= n_sealed) never appear in the sealed posting lists,
             # so this is a disjoint k-way merge, bit-identical across
             # replicas at one epoch
-            if w.view is not None and len(w.view.delta):
-                d_ids, d_d2 = w.view.delta_scan(q32, filt=p.filter)
+            if len(w.view.delta):
+                d_ids, d_d2 = w.view.delta_scan(
+                    np.asarray(w.queries[qi], np.float32),
+                    filt=w.plans[qi].filter)
                 if len(d_ids):
                     all_ids = np.concatenate([rr_ids.astype(np.int64),
                                               d_ids])
                     all_d = np.concatenate(
-                        [rr.dists, d_d2.astype(rr.dists.dtype)])
+                        [dists_out, d_d2.astype(dists_out.dtype)])
                     sel = np.lexsort((all_ids, all_d))[:p.k]
                     ids_out = all_ids[sel]
                     dists_out = all_d[sel]
-            t3, c3 = time.perf_counter(), time.thread_time()
+            answers.append((ids_out, dists_out))
+        G = len(members)
+        t_rerank = (time.perf_counter() - t0) / G
+        cpu_rerank = (time.thread_time() - c0) / G
+        B, u = len(w.queries), len(w.union)
+        for j, (qi, (ids_out, dists_out)) in enumerate(zip(members, answers)):
+            io = rr.io[j]
             stats = QueryStats(
-                ios=rr.io.ios, pages_requested=rr.io.pages_requested,
-                buffer_hits=rr.io.buffer_hits, ssd_bytes=rr.io.bytes_read,
+                ios=io.ios, pages_requested=io.pages_requested,
+                buffer_hits=io.buffer_hits, ssd_bytes=io.bytes_read,
                 h2d_bytes=4 * u // max(B, 1),    # amortised union transfer
                 candidates_scanned=u,            # union, ONCE per window
                 candidates_prefilter=w.prefilter,
-                rerank_batches=rr.batches_run,
-                rerank_scored=rr.candidates_scored,
-                early_stopped=rr.early_stopped,
+                rerank_batches=int(rr.batches_run[j]),
+                rerank_scored=int(rr.candidates_scored[j]),
+                early_stopped=bool(rr.early_stopped[j]),
                 graph_expansions=w.expansions[qi],
                 lists_empty=w.lists_empty[qi],
                 candidates_collected=w.collected[qi],
-                t_graph=w.t_graph / max(B, 1), t_rerank=t3 - t2,
-                cpu_graph=w.cpu_graph / max(B, 1), cpu_rerank=c3 - c2)
-            fut._set_result(QueryResult(ids=ids_out, dists=dists_out,
-                                        stats=stats))
+                rerank_width=width,
+                t_graph=w.t_graph / max(B, 1), t_rerank=t_rerank,
+                cpu_graph=w.cpu_graph / max(B, 1), cpu_rerank=cpu_rerank)
+            futures[w.start + qi]._set_result(QueryResult(
+                ids=ids_out, dists=dists_out, stats=stats))
 
     # --------------------------------------------------------------- submit
     def submit(self, queries, plan: Optional[QueryPlan] = None,

@@ -18,16 +18,19 @@ to the end of the fetch).  Every mechanism can be disabled independently.
 I/O counts and byte volumes are exact; latency is modelled by the analytic
 device model in ``core.baselines`` (no NVMe in this container — DESIGN.md §7).
 
-Thread-safety: the per-query DRAM buffer is thread-local, so the threaded
-serving runtime (PR 3) can re-rank two queries concurrently — each
-re-ranking thread sees its own per-query buffer scope and per-query I/O
-accounting stays exact.
+Two forms give the same counts.  ``fetch`` accounts one mini-batch at a
+time against the buffer of the current query scope (``begin_query``), one
+query at a time.  ``account`` counts a whole group of finished re-ranks at
+once from each query's rows and mini-batch count, and keeps no state, so
+concurrent re-ranks on the serving path share one ``SSDSim``: where a
+query touches no more distinct pages than the buffer holds, the LRU never
+evicts and each page costs one I/O in the mini-batch where it first
+appears; otherwise it replays ``fetch``'s walk with a buffer of its own.
 """
 
 from __future__ import annotations
 
 import dataclasses
-import threading
 from collections import OrderedDict
 from typing import Dict, List, Optional, Sequence, Tuple
 
@@ -164,29 +167,7 @@ class SSDSim:
         self.intra_merge = intra_merge
         self.use_buffer = use_buffer
         self.buffer_pages = buffer_pages
-        # one DRAM buffer per re-ranking thread: a query's re-rank runs
-        # entirely on one thread, so per-query scoping survives the
-        # threaded runtime's concurrent retirements
-        self._tls = threading.local()
-
-    @property
-    def buffer(self) -> PageBuffer:
-        buf = getattr(self._tls, "buffer", None)
-        if buf is None:
-            buf = PageBuffer(self.buffer_pages)
-            self._tls.buffer = buf
-        return buf
-
-    # thread-local state is not deepcopy/pickle-able; a copy starts with
-    # fresh (empty) per-thread buffers, which is also semantically right
-    def __getstate__(self):
-        state = self.__dict__.copy()
-        state.pop("_tls", None)
-        return state
-
-    def __setstate__(self, state):
-        self.__dict__.update(state)
-        self._tls = threading.local()
+        self.buffer = PageBuffer(buffer_pages)   # ``fetch``'s query scope
 
     def begin_query(self) -> IOStats:
         """Per-query buffer scope (the paper's DRAM buffer is per-query
@@ -203,13 +184,17 @@ class SSDSim:
         ``intra_merge=False`` same-page requests inside one batch each
         cost an I/O instead of being silently absorbed by the buffer
         (keeps the Fig. 12 per-mechanism attribution honest)."""
-        pages = self.layout.page_of[vec_ids]
+        self._account_batch(self.layout.page_of[vec_ids], stats, self.buffer)
+        return self.vectors[vec_ids]
+
+    def _account_batch(self, pages: np.ndarray, stats: IOStats,
+                       buf: PageBuffer) -> None:
+        """One mini-batch's page reads against ``buf``: ``fetch``'s rule."""
         stats.pages_requested += len(pages)
         wanted = pages if not self.intra_merge else np.unique(pages)
         # per-mechanism attribution invariant (Fig. 12):
         #   pages_requested - ios == intra_merged + buffer_hits
         stats.intra_merged += len(pages) - len(wanted)
-        buf = self.buffer
         read_this_batch: List[int] = []       # read order (dups included)
         for p in wanted:
             p = int(p)
@@ -224,7 +209,61 @@ class SSDSim:
             # actual read sequence (a repeat moves its page to the tail)
             for p in read_this_batch:
                 buf.insert(p)
-        return self.vectors[vec_ids]
+
+    def account(self, rows: Sequence[np.ndarray], batches_run: Sequence[int],
+                batch_size: int) -> List[IOStats]:
+        """Page I/O of finished re-ranks: query i read the first
+        ``batches_run[i]`` mini-batches of ``batch_size`` of ``rows[i]``.
+        Returns each query's counts, equal to what ``begin_query`` and one
+        ``fetch`` per mini-batch give."""
+        lens = [min(len(r), int(b) * batch_size)
+                for r, b in zip(rows, batches_run)]
+        out = [IOStats() for _ in lens]
+        replay = range(len(lens))
+        if self.intra_merge and self.use_buffer and sum(lens):
+            # one sort of (query, page, mini-batch) keys counts the
+            # distinct pages of each query and of each of its mini-batches
+            n_mb = -(-max(lens) // batch_size)
+            span = self.layout.n_pages * n_mb
+            offset, size = [], []
+            for i, n in enumerate(lens):
+                offset += [i * span + m for m in range(-(-n // batch_size))]
+                size += [batch_size] * (n // batch_size)
+                if n % batch_size:
+                    size.append(n % batch_size)
+            key = self.layout.page_of[np.concatenate(
+                [r[:n] for r, n in zip(rows, lens)])] * n_mb
+            key += np.repeat(offset, size)
+            key.sort()
+            page = key // n_mb
+            query = page // self.layout.n_pages
+            # the first key of each query opens a new page and mini-batch
+            per_mb = np.bincount(query[1:], key[1:] != key[:-1], len(lens))
+            distinct = np.bincount(query[1:], page[1:] != page[:-1],
+                                   len(lens))
+            per_mb[query[0]] += 1
+            distinct[query[0]] += 1
+            replay = []
+            for i, n in enumerate(lens):
+                # fits in the buffer: the LRU never evicts, so a page costs
+                # one I/O where it first appears and a hit in each later
+                # mini-batch that asks for it
+                if distinct[i] > self.buffer_pages:
+                    replay.append(i)
+                    continue
+                st = out[i]
+                st.pages_requested = n
+                st.ios = int(distinct[i])
+                st.intra_merged = n - int(per_mb[i])
+                st.buffer_hits = int(per_mb[i] - distinct[i])
+                st.bytes_read = st.ios * self.layout.page_bytes
+        for i in replay:
+            buf = PageBuffer(self.buffer_pages)
+            for b0 in range(0, lens[i], batch_size):
+                self._account_batch(
+                    self.layout.page_of[rows[i][b0:b0 + batch_size]],
+                    out[i], buf)
+        return out
 
 
 @dataclasses.dataclass

@@ -1,8 +1,19 @@
 """Heuristic re-ranking — paper §4.2, Algorithm 1.
 
 Host version (numpy): the production placement — the CPU re-ranks using raw
-vectors fetched from the SSD tier (``core.io_sim``), max-heap top-k, change
-rate Δ = |S_n − S_n∩S_{n−1}|/k, early termination after β stable batches.
+vectors read from the SSD tier (``core.io_sim``): top-k by exact squared
+L2, change rate Δ = |S_n − S_n∩S_{n−1}|/k (Eq. 3), early termination after
+β stable mini-batches.  It runs in lockstep over a block of queries (the
+landed scan window): step b scores mini-batch b of every query still
+active with one gather and one distance reduction, and merges the scores
+into a (B, k) top-k.  Δ and the β count are kept per query, and a query
+drops out when it stops early or runs out of candidates, so each scores
+exactly the mini-batches it would alone and reads no raw vector past its
+early stop.  One query is a block of one.  The top-k is ordered by
+(distance, id), the scan merge's rule, so at exactly equal float32
+distance the smaller id is kept.  Page I/O is counted after the loop from
+each query's mini-batch count (``SSDSim.account``): no decision of
+Algorithm 1 depends on it.
 
 Device version (``lax.while_loop``): same control flow with a fixed-size
 top-k buffer, for TPU-resident re-ranking when raw vectors live in HBM
@@ -12,8 +23,7 @@ top-k buffer, for TPU-resident re-ranking when raw vectors live in HBM
 from __future__ import annotations
 
 import dataclasses
-import heapq
-from typing import Optional, Tuple
+from typing import List, Union
 
 import jax
 import jax.numpy as jnp
@@ -21,64 +31,119 @@ import numpy as np
 
 from repro.core.io_sim import IOStats, SSDSim
 
+# A scored candidate is one int64 key, its float32 distance's bits (which
+# order as the distances do, for d >= 0) above its row (< 2**32 - 2), so
+# one sort orders by (distance, row).  An empty top-k slot sorts after any
+# scored row and before a ragged mini-batch's pad, so a pad is never kept.
+_PAD_ROW = 0xFFFFFFFF
+_EMPTY = (0x7F800000 << 32) | (_PAD_ROW - 1)          # inf, an empty slot
+
 
 @dataclasses.dataclass
 class RerankResult:
-    ids: np.ndarray                 # (k,) final neighbour ids (ascending dist)
-    dists: np.ndarray               # (k,)
-    batches_run: int
-    candidates_scored: int
-    io: IOStats
-    early_stopped: bool
+    """One query: ``ids``/``dists`` (n,) in ascending (distance, id) order,
+    n = min(k, candidates scored), scalar counters and one ``IOStats``.
+    A block of B queries: ``ids``/``dists`` (B, k), padded past each
+    row's n with id -1 and distance inf, each counter a (B,) array and
+    ``io`` a list of B ``IOStats``; ``row(i)`` is query i's own result."""
+
+    ids: np.ndarray
+    dists: np.ndarray
+    batches_run: Union[int, np.ndarray]
+    candidates_scored: Union[int, np.ndarray]
+    io: Union[IOStats, List[IOStats]]
+    early_stopped: Union[bool, np.ndarray]
+
+    def row(self, i: int) -> "RerankResult":
+        n = min(self.ids.shape[1], int(self.candidates_scored[i]))
+        return RerankResult(ids=self.ids[i, :n], dists=self.dists[i, :n],
+                            batches_run=int(self.batches_run[i]),
+                            candidates_scored=int(self.candidates_scored[i]),
+                            io=self.io[i],
+                            early_stopped=bool(self.early_stopped[i]))
 
 
-def heuristic_rerank(query: np.ndarray, candidate_ids: np.ndarray,
-                     ssd: SSDSim, k: int, *, batch_size: int = 32,
-                     eps: float = 0.05, beta: int = 2,
+def heuristic_rerank(query: np.ndarray, candidate_ids, ssd: SSDSim, k: int,
+                     *, batch_size: int = 32, eps: float = 0.05,
+                     beta: int = 2,
                      disable_early_stop: bool = False) -> RerankResult:
-    """Algorithm 1.  ``candidate_ids`` must be sorted by ascending PQ
-    distance (the GPU's output order — step ⑦)."""
-    q = query.astype(np.float32)
-    stats = ssd.begin_query()
-    heap: list = []                 # max-heap via negated dists
-    stability = 0
-    batches = 0
-    scored = 0
-    early = False
-    n = len(candidate_ids)
-
-    def heap_ids() -> set:
-        return {vid for _, vid in heap}
-
-    for start in range(0, n, batch_size):
-        prev = heap_ids()
-        batch = candidate_ids[start:start + batch_size]
-        vecs = ssd.fetch(batch, stats)                     # I/O + dedup
-        d = np.sum((vecs.astype(np.float32) - q[None]) ** 2, axis=1)
-        for dist, vid in zip(d, batch):
-            scored += 1
-            if len(heap) < k:
-                heapq.heappush(heap, (-dist, int(vid)))
-            elif dist < -heap[0][0]:
-                heapq.heapreplace(heap, (-dist, int(vid)))
-        batches += 1
-        cur = heap_ids()
-        delta = len(cur - prev) / max(k, 1)                # Eq. 3
+    """Algorithm 1 for one query ``(D,)`` with its candidate rows, or in
+    lockstep for a block ``(B, D)`` with a sequence of B candidate-row
+    arrays.  Each query's rows must be sorted by ascending PQ distance
+    (the scan's output order — step ⑦) and hold no repeats."""
+    if np.ndim(query) == 1:
+        return heuristic_rerank(
+            np.asarray(query)[None], [candidate_ids], ssd, k,
+            batch_size=batch_size, eps=eps, beta=beta,
+            disable_early_stop=disable_early_stop).row(0)
+    qs = np.asarray(query, np.float32)
+    cands: List[np.ndarray] = [np.asarray(c, np.int64)
+                               for c in candidate_ids]
+    lens = np.array([len(c) for c in cands], np.int64)
+    n_mb = -(-lens // batch_size)
+    steps = int(n_mb.max(initial=0))
+    # steps at which some query's last mini-batch is short
+    ragged = set((n_mb[lens % batch_size > 0] - 1).tolist())
+    batches = np.zeros(len(cands), np.int64)
+    early = np.zeros(len(cands), bool)
+    top = np.full((len(cands), k), _EMPTY, np.int64)
+    # Δ < eps  <=>  fewer than `few` new top-k members (Δ rises with them)
+    few = sum(f / max(k, 1) < eps for f in range(k + 1))
+    # the queries still active, compacted; each row of `work` holds its
+    # query's top-k keys in [:k] and the mini-batch being merged in [k:]
+    live = np.flatnonzero(n_mb)
+    q, left = qs[live], n_mb[live]
+    rows = np.full((len(live), steps * batch_size), -1, np.int64)
+    for j, i in enumerate(live):
+        rows[j, :lens[i]] = cands[i]
+    work = np.full((len(live), k + batch_size), _EMPTY, np.int64)
+    stable = np.zeros(len(live), np.int64)
+    for b in range(steps):
+        ids = rows[:, b * batch_size:(b + 1) * batch_size]
+        # row-wise float32 squared L2, the same reduction per row as
+        # scoring one query's mini-batch alone
+        if b not in ragged:
+            x = ssd.vectors[ids].astype(np.float32, copy=False) - q[:, None]
+            x *= x
+            d = np.add.reduce(x, -1)
+        else:
+            real = ids >= 0
+            x = (ssd.vectors[ids[real]].astype(np.float32, copy=False)
+                 - np.repeat(q, np.add.reduce(real, 1), 0))
+            x *= x
+            d = np.full(ids.shape, np.inf, np.float32)
+            d[real] = np.add.reduce(x, -1)
+            ids = np.where(real, ids, _PAD_ROW)
+        new = (d.view(np.int32).astype(np.int64) << 32) | ids
+        work[:, k:] = new
+        work.sort(1)
+        done = left == b + 1
         if not disable_early_stop:
-            if delta < eps:
-                stability += 1
-                if stability >= beta:
-                    early = True
-                    break
-            else:
-                stability = 0
-
-    order = sorted(((-nd, vid) for nd, vid in heap))
-    ids = np.array([vid for _, vid in order], np.int32)
-    dd = np.array([d for d, _ in order], np.float32)
-    return RerankResult(ids=ids, dists=dd, batches_run=batches,
-                        candidates_scored=scored, io=stats,
-                        early_stopped=early)
+            # Δ·k (Eq. 3): the mini-batch's members of the new top-k
+            calm = np.add.reduce(new < work[:, k:k + 1], 1) < few
+            stable += 1
+            stable *= calm
+            stop = stable >= beta
+            stop &= calm
+            done |= stop
+        if done.any():
+            out = live[done]
+            top[out] = work[done, :k]
+            batches[out] = b + 1
+            if not disable_early_stop:
+                early[out] = stop[done]
+            go = ~done
+            if not go.any():
+                break
+            live, q, left, rows, work, stable = (
+                live[go], q[go], left[go], rows[go], work[go], stable[go])
+    row = top & 0xFFFFFFFF
+    return RerankResult(
+        ids=np.where(row >= _PAD_ROW - 1, -1, row).astype(np.int32),
+        dists=(top >> 32).astype(np.int32).view(np.float32),
+        batches_run=batches,
+        candidates_scored=np.minimum(lens, batches * batch_size),
+        io=ssd.account(cands, batches, batch_size), early_stopped=early)
 
 
 def heuristic_rerank_jax(query: jax.Array, cand_vectors: jax.Array,

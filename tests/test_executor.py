@@ -249,3 +249,115 @@ def test_rollups_sum_the_collection_counters(anns_bundle):
             assert roll[field] == sum(getattr(s, field) for s in stats)
         assert roll["candidates_collected"] >= len(b.queries) * 10
         backend.stop()
+
+
+# ------------------------------------------------ stage ⑦ in lockstep
+
+# counters a window gives each of its queries from its shared union
+_WINDOW_SHARED = ("h2d_bytes", "candidates_scanned", "candidates_prefilter")
+
+
+def _each_alone(monkeypatch):
+    """Stage ⑦ with every query of a lockstep group re-ranked on its own,
+    the results stacked back into the group's block."""
+    import repro.core.executor as executor_mod
+    from repro.core.rerank import RerankResult
+    real = executor_mod.heuristic_rerank
+
+    def alone(queries, rows, ssd, k, **kw):
+        parts = [real(queries[j:j + 1], [r], ssd, k, **kw)
+                 for j, r in enumerate(rows)]
+        stack = {f: np.concatenate([getattr(p, f) for p in parts])
+                 for f in ("ids", "dists", "batches_run",
+                           "candidates_scored", "early_stopped")}
+        return RerankResult(io=[p.io[0] for p in parts], **stack)
+    monkeypatch.setattr(executor_mod, "heuristic_rerank", alone)
+
+
+@pytest.mark.parametrize("reference", ["window_of_one", "each_alone"])
+def test_lockstep_rerank_answers_as_each_query_alone(anns_bundle,
+                                                     monkeypatch, reference):
+    """A window of 8 with mixed ``k`` / ``top_n`` re-ranks in lockstep
+    groups; its answers and counters equal the same queries' at window 1
+    (all but the three counters a window shares out from its union) and,
+    in the same window, with each query re-ranked on its own (all)."""
+    from repro.core.executor import QUERY_STATS_FIELDS, PlanOverrides
+    b = anns_bundle
+    ex = b.index.executor
+    queries = np.asarray(b.queries[:8], np.float32)
+    overrides = [PlanOverrides(k=k, top_n=n) for k, n in
+                 [(10, None), (5, 64), (10, 100), (3, None), (5, 16),
+                  (10, 200), (1, 40), (10, 96)]]
+    got = ex.submit(queries, b.index.plan(window=8),
+                    overrides=overrides).results()
+    fields = QUERY_STATS_FIELDS
+    if reference == "each_alone":
+        _each_alone(monkeypatch)
+        want = ex.submit(queries, b.index.plan(window=8),
+                         overrides=overrides).results()
+    else:
+        want = ex.submit(queries, b.index.plan(window=1),
+                         overrides=overrides).results()
+        fields = [f for f in fields if f not in _WINDOW_SHARED]
+    for g, w, o in zip(got, want, overrides):
+        assert len(g.ids) == o.k
+        np.testing.assert_array_equal(g.ids, w.ids)
+        np.testing.assert_array_equal(g.dists, w.dists)
+        for f in fields:
+            assert getattr(g.stats, f) == getattr(w.stats, f), f
+        assert g.stats.early_stopped == w.stats.early_stopped
+    # the k = 10 group of four ran together at some step
+    assert max(g.stats.rerank_width for g in got) > 1.0
+    if reference == "window_of_one":
+        assert all(w.stats.rerank_width == 1.0 for w in want)
+
+
+def test_cancelled_and_expired_rows_skip_only_their_own_rerank(
+        anns_bundle, monkeypatch):
+    import repro.core.executor as executor_mod
+    from repro.core.executor import PlanOverrides
+    from repro.core.futures import DeadlineExceeded
+    b = anns_bundle
+    ex = b.index.executor
+    queries = np.asarray(b.queries[:8], np.float32)
+    plan = b.index.plan(window=8)
+    want = ex.submit(queries, plan).results()
+    seen = []
+    real = executor_mod.heuristic_rerank
+
+    def recording(block, rows, ssd, k, **kw):
+        seen.append(np.array(block))
+        return real(block, rows, ssd, k, **kw)
+    monkeypatch.setattr(executor_mod, "heuristic_rerank", recording)
+    overrides = [None] * 8
+    overrides[5] = PlanOverrides(deadline_s=0.0)
+    ticket = ex.submit(queries, plan, overrides=overrides)
+    assert ticket.futures[2].cancel()           # scan in flight, not landed
+    ticket.wait()
+    assert ticket.futures[2].cancelled()
+    with pytest.raises(DeadlineExceeded):
+        ticket.futures[5].result()
+    kept = [0, 1, 3, 4, 6, 7]
+    assert len(seen) == 1                        # one lockstep group
+    np.testing.assert_array_equal(seen[0], queries[kept])
+    for qi in kept:
+        r = ticket.futures[qi].result()
+        np.testing.assert_array_equal(r.ids, want[qi].ids)
+        np.testing.assert_array_equal(r.dists, want[qi].dists)
+
+
+def test_rerank_width_counts_the_queries_scored_together(anns_bundle):
+    """With no early stop a query runs top_n / rerank_batch mini-batches:
+    2, 1, 3 and 1 here, so its group of four takes 3 lockstep steps for 7
+    mini-batches, 7/3 queries a step; alone, each is 1."""
+    from repro.core.executor import PlanOverrides
+    b = anns_bundle
+    ex = b.index.executor
+    queries = np.asarray(b.queries[:4], np.float32)
+    for window, width in ((4, 7 / 3), (1, 1.0)):
+        plan = b.index.plan(window=window).override(disable_early_stop=True)
+        overrides = [PlanOverrides(top_n=n * plan.rerank_batch)
+                     for n in (2, 1, 3, 1)]
+        res = ex.submit(queries, plan, overrides=overrides).results()
+        assert [r.stats.rerank_batches for r in res] == [2, 1, 3, 1]
+        assert [r.stats.rerank_width for r in res] == [width] * 4

@@ -120,3 +120,137 @@ def test_topk_is_prefix_optimal(seed, k, batch):
     d = np.sum((data[scanned] - q) ** 2, -1)
     expect = scanned[np.argsort(d)[:k]]
     np.testing.assert_array_equal(np.sort(rr.ids), np.sort(expect))
+
+
+# ------------------------------------------------ the per-query reference
+# Algorithm 1 one query at a time, with a heap and a page walk per
+# mini-batch, as the host re-rank ran before it ran in lockstep: the
+# window form must give the same answer and the same counters row by row.
+
+def _ref_fetch(ssd, vec_ids, stats, buf):
+    pages = ssd.layout.page_of[vec_ids]
+    stats.pages_requested += len(pages)
+    wanted = pages if not ssd.intra_merge else np.unique(pages)
+    stats.intra_merged += len(pages) - len(wanted)
+    read = []
+    for p in wanted:
+        p = int(p)
+        if ssd.use_buffer and buf.hit(p):
+            stats.buffer_hits += 1
+            continue
+        stats.ios += 1
+        stats.bytes_read += ssd.layout.page_bytes
+        read.append(p)
+    if ssd.use_buffer:
+        for p in read:
+            buf.insert(p)
+    return ssd.vectors[vec_ids]
+
+
+def _ref_rerank(query, candidate_ids, ssd, k, *, batch_size=32, eps=0.05,
+                beta=2, disable_early_stop=False):
+    import heapq
+    from repro.core.io_sim import IOStats, PageBuffer
+    q = query.astype(np.float32)
+    stats, buf = IOStats(), PageBuffer(ssd.buffer_pages)
+    heap, stability, batches, scored, early = [], 0, 0, 0, False
+    for start in range(0, len(candidate_ids), batch_size):
+        prev = {vid for _, vid in heap}
+        batch = candidate_ids[start:start + batch_size]
+        vecs = _ref_fetch(ssd, batch, stats, buf)
+        d = np.sum((vecs.astype(np.float32) - q[None]) ** 2, axis=1)
+        for dist, vid in zip(d, batch):
+            scored += 1
+            if len(heap) < k:
+                heapq.heappush(heap, (-dist, int(vid)))
+            elif dist < -heap[0][0]:
+                heapq.heapreplace(heap, (-dist, int(vid)))
+        batches += 1
+        delta = len({vid for _, vid in heap} - prev) / max(k, 1)
+        if not disable_early_stop:
+            if delta < eps:
+                stability += 1
+                if stability >= beta:
+                    early = True
+                    break
+            else:
+                stability = 0
+    order = sorted((-nd, vid) for nd, vid in heap)
+    return (np.array([v for _, v in order], np.int32),
+            np.array([d for d, _ in order], np.float32),
+            batches, scored, early, stats)
+
+
+IO_FIELDS = ("ios", "pages_requested", "buffer_hits", "intra_merged",
+             "bytes_read")
+
+
+@pytest.mark.parametrize("case", [
+    dict(),
+    dict(eps=0.2, beta=1),
+    dict(eps=0.0, beta=3),
+    dict(eps=0.3, beta=0),
+    dict(eps=0.1, beta=4, batch_size=16),
+    dict(disable_early_stop=True),
+    dict(intra_merge=False),
+    dict(use_buffer=False),
+    dict(intra_merge=False, use_buffer=False),
+    dict(buffer_pages=3),                  # the LRU evicts: the replay
+    dict(buffer_pages=3, intra_merge=False, batch_size=8),
+    dict(k=1, batch_size=8),
+    dict(k=40),                            # more than some queries score
+], ids=lambda c: "-".join(f"{k}={v}" for k, v in c.items()) or "default")
+def test_window_form_matches_per_query_reference(case):
+    """A block of queries against the frozen per-query form, row by row:
+    ids, dists, counters and every I/O counter.  Candidate counts of 0,
+    under one mini-batch, whole mini-batches and ragged tails."""
+    case = dict(case)
+    ssd_kw = {key: case.pop(key) for key in ("intra_merge", "use_buffer",
+                                             "buffer_pages") if key in case}
+    k = case.pop("k", 10)
+    rng = np.random.default_rng(11)
+    n, d = 400, 16
+    data = rng.standard_normal((n, d)).astype(np.float32)
+    lay = StorageLayout.build(rng.integers(0, 12, n).astype(np.int64), 12,
+                              4 * d)
+    ssd = SSDSim(data, lay, **ssd_kw)
+    qs = rng.standard_normal((7, d)).astype(np.float32)
+    cands = []
+    for q, m in zip(qs, [0, 5, 32, 100, 161, 400, 250]):
+        # the scan's order: PQ-like distances, the true ones plus noise
+        noisy = np.sum((data - q) ** 2, -1) + rng.normal(0, 4.0, n)
+        cands.append(np.argsort(noisy)[:m])
+    block = heuristic_rerank(qs, cands, ssd, k, **case)
+    assert block.ids.shape == block.dists.shape == (len(qs), k)
+    for i, (q, c) in enumerate(zip(qs, cands)):
+        ids, dists, batches, scored, early, io = _ref_rerank(q, c, ssd, k,
+                                                             **case)
+        for got in (block.row(i), heuristic_rerank(q, c, ssd, k, **case)):
+            np.testing.assert_array_equal(got.ids, ids)
+            np.testing.assert_array_equal(got.dists, dists)
+            assert (got.batches_run, got.candidates_scored,
+                    got.early_stopped) == (batches, scored, early)
+            for f in IO_FIELDS:
+                assert getattr(got.io, f) == getattr(io, f), f
+    assert block.batches_run[0] == 0 and block.ids[0].tolist() == [-1] * k
+
+
+def test_equal_distances_keep_the_smaller_id():
+    """At exactly equal float32 distance the top-k keeps the smaller id
+    and lists it first, wherever the two sit in the candidate order."""
+    rng = np.random.default_rng(3)
+    data = rng.standard_normal((64, 8)).astype(np.float32)
+    data[9] = data[40]                      # rows 9 and 40 tie exactly
+    lay = StorageLayout.build(np.zeros(64, np.int64), 1, 32)
+    ssd = SSDSim(data, lay)
+    q = data[40] + 0.5
+    far = np.argsort(np.sum((data - q) ** 2, -1))[::-1]
+    far = far[(far != 9) & (far != 40)][:30]
+    for cand in ([40, 9], [9, 40], [40, *far, 9]):
+        one = heuristic_rerank(q, np.array(cand), ssd, k=1, batch_size=4,
+                               disable_early_stop=True)
+        assert one.ids.tolist() == [9]
+        two = heuristic_rerank(q, np.array(cand), ssd, k=2, batch_size=4,
+                               disable_early_stop=True)
+        assert two.ids.tolist() == [9, 40]
+        assert two.dists[0] == two.dists[1]
