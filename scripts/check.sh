@@ -4,8 +4,7 @@
 #   scripts/check.sh            tier-1: full default suite (slow deselected
 #                               via pytest.ini), no pytest cache, hard
 #                               wall-clock guard
-#   scripts/check.sh smoke      fast executor/engine subset (used by
-#                               benchmarks/run.py --selftest)
+#   scripts/check.sh smoke      fast executor/engine subset
 #   scripts/check.sh threaded-stress
 #                               threaded serving runtime: 8 producer
 #                               threads against one replica, id-parity
@@ -22,10 +21,8 @@
 #                               concurrent asyncio coroutines over a
 #                               2-replica router, awaited-admission
 #                               backpressure, zero leaked futures
-#   scripts/check.sh kernels    kernel parity tests + micro-benchmarks;
-#                               persists BENCH_kernels.json and fails on
-#                               rows slower than BENCH_REGRESSION_FACTOR
-#                               (default 1.6) x the previous artifact
+#   scripts/check.sh kernels    kernel parity tests: Pallas (the CPU
+#                               interpreter) vs the pure-jnp oracles
 #   scripts/check.sh edge-stress
 #                               HTTP edge + autoscaler: auth/rate-limit/
 #                               error codes over a real socket, coalesced
@@ -56,13 +53,6 @@
 #                               deadlock detector (LO*), jit/hot-path
 #                               purity lints (PU*).  Zero findings or
 #                               non-zero exit.  See DESIGN.md §9.
-#   scripts/check.sh fig9       throughput/latency figure as a ratchet:
-#                               persists BENCH_fig9.json (incl. the
-#                               edge_http socket row) and fails on rows
-#                               slower than BENCH_REGRESSION_FACTOR x the
-#                               previous artifact.  Scale pinned via
-#                               REPRO_BENCH_N / REPRO_BENCH_QUERIES so
-#                               the committed artifact and CI agree.
 #   scripts/check.sh full       everything, including @slow system tests
 #
 # CHECK_TIMEOUT overrides the guard (seconds).
@@ -113,23 +103,15 @@ case "$MODE" in
         tests/test_tenants.py tests/test_edge.py
     ;;
   kernels)
-    timeout "${CHECK_TIMEOUT:-600}" \
+    exec timeout "${CHECK_TIMEOUT:-600}" \
       python -m pytest -x -q -p no:cacheprovider tests/test_kernels.py \
         tests/test_kernel_props.py
-    exec timeout "${CHECK_TIMEOUT:-600}" \
-      python -m benchmarks.run --only kernels --persist
     ;;
   edge-stress)
     export LINT_LOCKS="${LINT_LOCKS:-1}"
     exec timeout "${CHECK_TIMEOUT:-600}" \
       python -m pytest -x -q -p no:cacheprovider tests/test_edge.py \
         tests/test_autoscaler.py tests/test_coalesce.py
-    ;;
-  fig9)
-    export REPRO_BENCH_N="${REPRO_BENCH_N:-12000}"
-    export REPRO_BENCH_QUERIES="${REPRO_BENCH_QUERIES:-32}"
-    exec timeout "${CHECK_TIMEOUT:-900}" \
-      python -m benchmarks.run --only fig9 --persist
     ;;
   tier1)
     exec timeout "${CHECK_TIMEOUT:-600}" \
@@ -140,7 +122,7 @@ case "$MODE" in
       python -m pytest -x -q -p no:cacheprovider -m ""
     ;;
   *)
-    echo "usage: scripts/check.sh [tier1|smoke|lint|threaded-stress|router-stress|async-stress|mutate-stress|filter-stress|kernels|edge-stress|fig9|full]" >&2
+    echo "usage: scripts/check.sh [tier1|smoke|lint|threaded-stress|router-stress|async-stress|mutate-stress|filter-stress|kernels|edge-stress|full]" >&2
     exit 2
     ;;
 esac

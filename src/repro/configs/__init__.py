@@ -1,12 +1,1 @@
-from repro.configs.base import (  # noqa: F401
-    ANNSConfig,
-    GNNConfig,
-    LMConfig,
-    RecsysConfig,
-    ShapeCell,
-    LM_SHAPES,
-    GNN_SHAPES,
-    RECSYS_SHAPES,
-    shapes_for,
-)
-from repro.configs.registry import ARCH_IDS, get_config, get_both  # noqa: F401
+from repro.configs.base import ANNSConfig  # noqa: F401

@@ -20,8 +20,7 @@ import jax.numpy as jnp
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.kernels.pq_adc.ref import pq_adc_ref
-from repro.models.layers import ShardCtx
-from repro.sharding.spec import shard_map
+from repro.sharding.spec import ShardCtx, shard_map
 
 
 def window_scan_ready(*arrays) -> bool:

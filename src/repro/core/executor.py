@@ -77,7 +77,7 @@ from repro.core import pq
 from repro.core.filters import Predicate
 from repro.core.futures import BatchTicket, DeadlineExceeded, QueryFuture
 from repro.core.rerank import heuristic_rerank
-from repro.models.layers import ShardCtx
+from repro.sharding.spec import ShardCtx, rules_for_mesh
 
 if TYPE_CHECKING:  # pragma: no cover
     from repro.core.engine import FusionANNSIndex
@@ -380,7 +380,6 @@ class QueryExecutor:
         replicas never contend for a chip).  Every device operand is
         committed to the mesh at dispatch, so nothing leaks onto devices
         outside the group."""
-        from repro.sharding.spec import rules_for_mesh
         rules = rules_for_mesh(mesh)
         # ``jax.make_mesh`` builds Explicit-typed axes, under which a
         # gather from the row-sharded codes must name its out-sharding at

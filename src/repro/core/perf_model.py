@@ -63,9 +63,8 @@ class QueryDemand:
 def demand_from_stats(totals: Dict[str, float], n: float, *, pq_m: int,
                       dim: int, top_m: int) -> QueryDemand:
     """Mean per-query demand from summed ``QueryStats`` counters covering
-    ``n`` responses — the ONE stats-to-demand conversion shared by the
-    benchmark harness (``benchmarks.common.fusion_demand``) and the
-    router's replica-scaling sweep (``ReplicaRouter.measured_demand``)."""
+    ``n`` responses — the stats-to-demand conversion of the router's
+    replica-scaling sweep (``ReplicaRouter.measured_demand``)."""
     n = max(n, 1)
     return QueryDemand(
         ssd_ios=totals["ios"] / n,

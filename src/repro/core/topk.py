@@ -1,8 +1,7 @@
 """Two-level distributed top-k (shard-local top-k -> all_gather -> merge).
 
 This is the collective pattern FusionANNS needs for its sharded ADC scan
-(step 7: per-shard candidate lists merged into the global top-n), and it is
-reused by the recsys retrieval/serving steps (score vs 10^6 items).  Only
+(step 7: per-shard candidate lists merged into the global top-n).  Only
 (k x n_shards) (value, id) pairs cross the interconnect — never the scores.
 """
 
@@ -14,8 +13,7 @@ import jax
 import jax.numpy as jnp
 from jax.sharding import Mesh, PartitionSpec as P
 
-from repro.models.layers import ShardCtx
-from repro.sharding.spec import shard_map
+from repro.sharding.spec import ShardCtx, shard_map
 
 Axes = Union[str, Tuple[str, ...]]
 

@@ -1,13 +1,1 @@
-from repro.data.synthetic import (  # noqa: F401
-    clustered_vectors,
-    lm_batch,
-    recsys_dlrm_batch,
-    recsys_seq_batch,
-    recsys_sparse_batch,
-)
-from repro.data.graphs import (  # noqa: F401
-    block_diagonal_batch,
-    build_csr,
-    neighbor_sample,
-    random_graph,
-)
+from repro.data.synthetic import clustered_vectors  # noqa: F401

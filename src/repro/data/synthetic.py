@@ -7,15 +7,9 @@ actually exercised rather than vacuous, as they would be on iid uniform data.
 
 from __future__ import annotations
 
-from typing import Dict, Optional, Tuple
+from typing import Optional
 
 import numpy as np
-
-
-def lm_batch(rng: np.random.Generator, batch: int, seq: int,
-             vocab: int) -> Dict[str, np.ndarray]:
-    tokens = rng.integers(0, vocab, (batch, seq + 1), dtype=np.int32)
-    return {"tokens": tokens[:, :-1], "labels": tokens[:, 1:]}
 
 
 def clustered_vectors(rng: np.random.Generator, n: int, dim: int,
@@ -33,32 +27,3 @@ def clustered_vectors(rng: np.random.Generator, n: int, dim: int,
         x = np.clip(np.round(128 * x), lo, hi)
     return x.astype(dtype)
 
-
-def recsys_dlrm_batch(rng: np.random.Generator, batch: int, n_dense: int,
-                      n_sparse: int, vocab: int,
-                      multi_hot: int = 1) -> Dict[str, np.ndarray]:
-    return {
-        "dense": rng.standard_normal((batch, n_dense)).astype(np.float32),
-        "sparse_ids": rng.integers(0, vocab, (batch, n_sparse, multi_hot),
-                                   dtype=np.int32),
-        "labels": rng.integers(0, 2, (batch,)).astype(np.float32),
-    }
-
-
-def recsys_sparse_batch(rng: np.random.Generator, batch: int, n_sparse: int,
-                        vocab: int, multi_hot: int = 1):
-    return {
-        "sparse_ids": rng.integers(0, vocab, (batch, n_sparse, multi_hot),
-                                   dtype=np.int32),
-        "labels": rng.integers(0, 2, (batch,)).astype(np.float32),
-    }
-
-
-def recsys_seq_batch(rng: np.random.Generator, batch: int, seq: int,
-                     vocab: int, n_neg: int = 127) -> Dict[str, np.ndarray]:
-    return {
-        "item_ids": rng.integers(0, vocab, (batch, seq), dtype=np.int32),
-        "mask_pos": rng.integers(0, seq, (batch,), dtype=np.int32),
-        "pos_items": rng.integers(0, vocab, (batch,), dtype=np.int32),
-        "neg_items": rng.integers(0, vocab, (batch, n_neg), dtype=np.int32),
-    }

@@ -2,9 +2,8 @@
 
 On a v5e pod each host runs this same program; `init_distributed()` wires
 jax.distributed from the scheduler environment (GKE/TPU-VM metadata or
-explicit env), after which `jax.devices()` spans the pod and
-`make_production_mesh()` builds the global mesh.  Per-host data sharding
-follows `host_batch_slice`.
+explicit env), after which `jax.devices()` spans the pod.  Per-host data
+sharding follows `host_batch_slice`.
 
 This container has a single process; the functions degrade to no-ops so
 every launcher works unchanged locally (unit-tested)."""

@@ -1,9 +1,7 @@
-"""Production mesh definitions.
-
-``make_production_mesh`` is a FUNCTION (never a module-level constant) so
-importing this module does not touch jax device state; dryrun.py sets
-``XLA_FLAGS=--xla_force_host_platform_device_count=512`` before any jax
-import to obtain placeholder devices."""
+"""Meshes for the scan: a small test mesh over whatever devices exist, and
+the disjoint device groups that multi-replica serving carves from one mesh.
+Building a mesh is a FUNCTION call, never a module-level constant, so
+importing this module does not touch jax device state."""
 
 from __future__ import annotations
 
@@ -11,14 +9,6 @@ from typing import List
 
 import jax
 import numpy as np
-
-
-def make_production_mesh(*, multi_pod: bool = False):
-    """Single pod: 16x16 = 256 chips (data, model).
-    Multi-pod: 2x16x16 = 512 chips (pod, data, model)."""
-    shape = (2, 16, 16) if multi_pod else (16, 16)
-    axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
 
 
 def make_test_mesh(n_devices: int | None = None, *, multi_pod: bool = False):

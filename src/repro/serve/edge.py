@@ -41,8 +41,7 @@ HTTP/1.1 parsing — no web framework in the image, none needed):
   futures leak at either level (tests/test_edge.py).
 
 :class:`HttpConn` is the matching minimal keep-alive client used by the
-tests, the benchmark harness (``benchmarks.common.edge_http_latency``)
-and the example; production callers can use anything that speaks HTTP.
+tests and the example; production callers can use anything that speaks HTTP.
 """
 
 from __future__ import annotations
@@ -431,13 +430,13 @@ class AnnsEdge:
 
 
 # ---------------------------------------------------------------------------
-# Minimal keep-alive HTTP client (tests / benchmarks / example)
+# Minimal keep-alive HTTP client (tests / example)
 # ---------------------------------------------------------------------------
 
 class HttpConn:
     """One keep-alive HTTP/1.1 connection speaking JSON — just enough
     client to exercise the edge through a real socket (the tests' and
-    benchmark harness's counterpart to :class:`AnnsEdge`)."""
+    the example's counterpart to :class:`AnnsEdge`)."""
 
     def __init__(self, reader: asyncio.StreamReader,
                  writer: asyncio.StreamWriter):

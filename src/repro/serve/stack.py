@@ -5,8 +5,8 @@ same ``ReplicaRouter(...)`` call with slightly divergent knob sets;
 :func:`make_serving_stack` is the single place that turns a
 :class:`ServingStackConfig` into a started router, so the serving shape
 (replica count, policy, batching window, pipeline depth, accuracy knobs)
-is declared once and reused everywhere — examples/serve_anns.py,
-examples/rag_pipeline.py, and the HTTP edge all build on it.
+is declared once and reused everywhere — examples/serve_anns.py, the
+benchmark and the HTTP edge all build on it.
 """
 
 from __future__ import annotations

@@ -1,9 +1,8 @@
 from repro.sharding.spec import (  # noqa: F401
+    LOCAL_CTX,
+    MULTI_POD_RULES,
     Rules,
     SINGLE_POD_RULES,
-    MULTI_POD_RULES,
-    LOCAL_RULES,
-    constrain,
-    local_rules_for_mesh,
+    ShardCtx,
     rules_for_mesh,
 )

@@ -10,18 +10,21 @@ Policy notes
 * One small FusionANNS index is built ONCE per session (``anns_bundle``)
   and shared by the engine / system / executor / service / updates
   modules; tests that mutate the index (insert/delete) take the
-  ``fresh_index`` deep copy instead of rebuilding.
+  ``fresh_index`` deep copy instead of rebuilding.  ``bench_shape`` builds
+  the same index at the shapes the chip serves, and ``any_shape`` at all
+  three, once per session each.
 * Heavy system tests carry ``@pytest.mark.slow`` and are deselected by
   default via pytest.ini; run them with ``-m ""`` or
   ``scripts/check.sh full``.
 """
 
 import dataclasses
+import functools
 import os
 import sys
 
-# smoke tests / benches must see exactly 1 device (dryrun.py alone forces
-# 512); keep any user XLA_FLAGS out of the test environment.
+# tests must see exactly 1 device (the multi-device tests force theirs in a
+# subprocess); keep any user XLA_FLAGS out of the test environment.
 os.environ.pop("XLA_FLAGS", None)
 
 sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
@@ -65,15 +68,26 @@ class ANNSBundle:
     index: object
 
 
-@pytest.fixture(scope="session")
-def anns_bundle() -> ANNSBundle:
+# (n, dim, pq_m) of each shape the shared indexes are built at, with
+# SIFT_SMALL's other knobs: the 32-d tier-1 shape, and the two the chip
+# serves (bench/configs): SIFT's 128-d with M = 32 and DEEP's 96-d with
+# M = 24, whose scan width is not a multiple of 32.
+SHAPES = {"sift32m8": (2500, 32, 8),
+          "sift128m32": (3000, 128, 32),
+          "deep96m24": (3000, 96, 24)}
+BENCH_SHAPES = ("sift128m32", "deep96m24")
+
+
+@functools.lru_cache(maxsize=None)
+def build_bundle(shape: str) -> ANNSBundle:
+    """Build the shared index at ``shape`` once per process."""
     from repro.configs.anns_datasets import SIFT_SMALL
     from repro.core.engine import FusionANNSIndex, ground_truth
     from repro.data.synthetic import clustered_vectors
 
+    n, dim, pq_m = SHAPES[shape]
     rng = np.random.default_rng(0)
-    n, dim = 2500, 32
-    cfg = dataclasses.replace(SIFT_SMALL, n_vectors=n, dim=dim,
+    cfg = dataclasses.replace(SIFT_SMALL, n_vectors=n, dim=dim, pq_m=pq_m,
                               n_posting_fraction=0.02)
     everything = clustered_vectors(rng, n + 40, dim, n_clusters=24)
     data, new_vecs, queries = (everything[:n], everything[n:n + 20],
@@ -82,6 +96,23 @@ def anns_bundle() -> ANNSBundle:
     gt = ground_truth(data, queries, 10)
     return ANNSBundle(cfg=cfg, data=data, new_vecs=new_vecs,
                       queries=queries, gt=gt, index=index)
+
+
+@pytest.fixture(scope="session")
+def anns_bundle() -> ANNSBundle:
+    return build_bundle("sift32m8")
+
+
+@pytest.fixture(scope="session", params=BENCH_SHAPES)
+def bench_shape(request) -> ANNSBundle:
+    """The shared index at each of the shapes the chip serves."""
+    return build_bundle(request.param)
+
+
+@pytest.fixture(scope="session", params=list(SHAPES))
+def any_shape(request) -> ANNSBundle:
+    """The shared index at the tier-1 shape and at each served shape."""
+    return build_bundle(request.param)
 
 
 @pytest.fixture

@@ -7,30 +7,37 @@ import pytest
 from repro.core.baselines import HIPq, RummyLike, SpannLike
 from repro.core.engine import FusionANNSIndex, recall_at_k
 
-DIM = 32
+
+def _unpack(b):
+    return b.cfg, b.data, b.index, b.queries, b.gt
 
 
 @pytest.fixture(scope="module")
 def setup(anns_bundle):
-    b = anns_bundle        # session-scoped shared index (conftest.py)
-    return b.cfg, b.data, b.index, b.queries, b.gt
+    return _unpack(anns_bundle)   # session-scoped shared index (conftest.py)
 
 
-def test_recall_meets_paper_bar(setup):
-    cfg, data, index, queries, gt = setup
+@pytest.fixture(scope="module")
+def shaped(any_shape):
+    """The shared index at the tier-1 shape and at each served shape."""
+    return _unpack(any_shape)
+
+
+def test_recall_meets_paper_bar(shaped):
+    cfg, data, index, queries, gt = shaped
     res = index.batch_query(queries)
     rec = recall_at_k(np.stack([r.ids for r in res]), gt, 10)
     assert rec >= 0.90        # paper's operating point Recall@10 >= 0.9
 
 
-def test_h2d_is_ids_only(setup):
+def test_h2d_is_ids_only(shaped):
     """Multi-tiered index invariant: host->device traffic is 4 B per
     candidate id, never vector payload."""
-    cfg, data, index, queries, gt = setup
+    cfg, data, index, queries, gt = shaped
     r = index.query(queries[0])
     assert r.stats.h2d_bytes == 4 * r.stats.candidates_scanned
     # SPANN-equivalent would ship whole posting lists (>= dim bytes/vec)
-    assert r.stats.h2d_bytes < r.stats.candidates_scanned * DIM
+    assert r.stats.h2d_bytes < r.stats.candidates_scanned * cfg.dim
 
 
 def test_fusionanns_fewer_ios_than_spann(setup):
@@ -44,8 +51,8 @@ def test_fusionanns_fewer_ios_than_spann(setup):
     assert f_ios < s_ios
 
 
-def test_heuristic_rerank_cuts_ios(setup):
-    cfg, data, index, queries, gt = setup
+def test_heuristic_rerank_cuts_ios(shaped):
+    cfg, data, index, queries, gt = shaped
     with_hr = [index.query(q) for q in queries]
     without = [index.query(q, disable_early_stop=True) for q in queries]
     assert (np.mean([r.stats.ios for r in with_hr])
@@ -56,8 +63,8 @@ def test_heuristic_rerank_cuts_ios(setup):
     assert rec_hr >= rec_full - 0.05
 
 
-def test_dedup_cuts_ios(setup):
-    cfg, data, index, queries, gt = setup
+def test_dedup_cuts_ios(shaped):
+    cfg, data, index, queries, gt = shaped
     no_dedup = FusionANNSIndex(
         cfg=index.cfg, codebook=index.codebook, codes=index.codes,
         posting=index.posting, graph=index.graph,
@@ -94,13 +101,12 @@ def test_rummy_moves_vectors_fusionanns_moves_ids(setup):
     assert rd.h2d_bytes > fr.h2d_bytes      # PCIe traffic gap (Fig. 4d)
 
 
-def test_fused_batch_matches_per_query(setup):
+def test_fused_batch_matches_per_query(shaped):
     """Beyond-paper fused batch scan returns the same neighbours as the
     per-query path, while scanning the candidate UNION once."""
-    cfg, data, index, queries, gt = setup
+    cfg, data, index, queries, gt = shaped
     per = index.batch_query(queries[:8])
     fused = index.query_batch_fused(queries[:8])
-    from repro.core.engine import recall_at_k
     r_per = recall_at_k(np.stack([r.ids for r in per]), gt[:8], 10)
     r_fused = recall_at_k(np.stack([r.ids for r in fused]), gt[:8], 10)
     assert r_fused >= r_per - 0.03

@@ -24,8 +24,8 @@ from repro.core.executor import QueryPlan
 
 
 @pytest.fixture(scope="module")
-def paths(anns_bundle):
-    b = anns_bundle
+def paths(any_shape):
+    b = any_shape
     single = [b.index.query(q) for q in b.queries]
     batch = b.index.batch_query(b.queries)
     fused = b.index.query_batch_fused(b.queries)
@@ -69,15 +69,16 @@ def test_stats_accounting_invariants(paths):
     assert fused[0].stats.h2d_bytes == 4 * u // B
 
 
-def test_masked_topk_batch_matches_reference(rng):
+@pytest.mark.parametrize("m", [8, 24, 32])
+def test_masked_topk_batch_matches_reference(rng, m):
     """The executor's single-device bucket scan == brute ref over the
-    gathered bucket rows."""
+    gathered bucket rows, at the tier-1 code width and the served ones."""
     from repro.core.distributed import sharded_adc_topn_window
     from repro.kernels.pq_adc.ref import pq_adc_batch_ref
-    from repro.models.layers import ShardCtx
-    codes = jnp.asarray(rng.integers(0, 256, (2048, 8)), jnp.uint8)
+    from repro.sharding import ShardCtx
+    codes = jnp.asarray(rng.integers(0, 256, (2048, m)), jnp.uint8)
     rows = jnp.asarray(rng.choice(2048, 512, replace=False), jnp.int32)
-    luts = jnp.asarray(rng.random((3, 8, 256)), jnp.float32)
+    luts = jnp.asarray(rng.random((3, m, 256)), jnp.float32)
     mask = jnp.asarray(rng.random((3, 512)) < 0.5)
     vals, pos = sharded_adc_topn_window(codes, rows, luts, mask, 32,
                                         ShardCtx())
@@ -149,19 +150,20 @@ def test_sharded_scan_matches_single_device(sharded_results, key):
 # ---------------------------------------------------------------------------
 
 @pytest.fixture(scope="module")
-def attributed(anns_bundle):
+def attributed(any_shape):
     from repro.core.engine import FusionANNSIndex
-    b = anns_bundle
-    return FusionANNSIndex.build(
+    b = any_shape
+    return b, FusionANNSIndex.build(
         b.data, b.cfg, attributes={"cat": np.arange(len(b.data)) % 4})
 
 
 def test_lockstep_window_collects_what_each_query_collects_alone(
-        anns_bundle, attributed):
+        attributed):
     from test_navgraph import oracle_search
     from repro.core.executor import PlanOverrides
     from repro.core.filters import Eq
-    queries = np.asarray(anns_bundle.queries[:8], np.float32)
+    b, attributed = attributed
+    queries = np.asarray(b.queries[:8], np.float32)
     overrides = [PlanOverrides(top_m=m, filter=Eq("cat", 1) if i == 5
                                else None)
                  for i, m in enumerate([16, 4, 24, 8, 16, 12, 2, 30])]
@@ -193,13 +195,14 @@ def _lists_and_rows(view, q, top_m):
     return cids, [view.posting.members[c] for c in cids]
 
 
-def test_collection_counters_are_exact(fresh_index, anns_bundle):
+def test_collection_counters_are_exact(any_shape):
     """``lists_empty`` and ``candidates_collected`` against a count made
     here: on the built index (no list empty), then on a view with one of
     a query's lists emptied and another one's rows all tombstoned."""
+    import copy
     import dataclasses
     from repro.core.clustering import PostingLists
-    ix, queries = fresh_index, anns_bundle.queries
+    ix, queries = copy.deepcopy(any_shape.index), any_shape.queries
     top_m = ix.cfg.top_m
     for q, r in zip(queries, ix.batch_query(queries)):
         _, lists = _lists_and_rows(ix.view(), q, top_m)
@@ -231,11 +234,11 @@ def test_collection_counters_are_exact(fresh_index, anns_bundle):
             assert r.stats.candidates_collected == collected
 
 
-def test_rollups_sum_the_collection_counters(anns_bundle):
+def test_rollups_sum_the_collection_counters(any_shape):
     from repro.serve.anns_service import BatchingANNSService
     from repro.serve.client import SearchRequest
     from repro.serve.router import ReplicaRouter
-    b = anns_bundle
+    b = any_shape
     for backend in (BatchingANNSService(b.index, max_batch=4,
                                         max_wait_s=0.0),
                     ReplicaRouter(b.index, n_replicas=2,
@@ -275,14 +278,14 @@ def _each_alone(monkeypatch):
 
 
 @pytest.mark.parametrize("reference", ["window_of_one", "each_alone"])
-def test_lockstep_rerank_answers_as_each_query_alone(anns_bundle,
+def test_lockstep_rerank_answers_as_each_query_alone(any_shape,
                                                      monkeypatch, reference):
     """A window of 8 with mixed ``k`` / ``top_n`` re-ranks in lockstep
     groups; its answers and counters equal the same queries' at window 1
     (all but the three counters a window shares out from its union) and,
     in the same window, with each query re-ranked on its own (all)."""
     from repro.core.executor import QUERY_STATS_FIELDS, PlanOverrides
-    b = anns_bundle
+    b = any_shape
     ex = b.index.executor
     queries = np.asarray(b.queries[:8], np.float32)
     overrides = [PlanOverrides(k=k, top_n=n) for k, n in
@@ -313,11 +316,11 @@ def test_lockstep_rerank_answers_as_each_query_alone(anns_bundle,
 
 
 def test_cancelled_and_expired_rows_skip_only_their_own_rerank(
-        anns_bundle, monkeypatch):
+        any_shape, monkeypatch):
     import repro.core.executor as executor_mod
     from repro.core.executor import PlanOverrides
     from repro.core.futures import DeadlineExceeded
-    b = anns_bundle
+    b = any_shape
     ex = b.index.executor
     queries = np.asarray(b.queries[:8], np.float32)
     plan = b.index.plan(window=8)
@@ -346,12 +349,12 @@ def test_cancelled_and_expired_rows_skip_only_their_own_rerank(
         np.testing.assert_array_equal(r.dists, want[qi].dists)
 
 
-def test_rerank_width_counts_the_queries_scored_together(anns_bundle):
+def test_rerank_width_counts_the_queries_scored_together(any_shape):
     """With no early stop a query runs top_n / rerank_batch mini-batches:
     2, 1, 3 and 1 here, so its group of four takes 3 lockstep steps for 7
     mini-batches, 7/3 queries a step; alone, each is 1."""
     from repro.core.executor import PlanOverrides
-    b = anns_bundle
+    b = any_shape
     ex = b.index.executor
     queries = np.asarray(b.queries[:4], np.float32)
     for window, width in ((4, 7 / 3), (1, 1.0)):

@@ -232,34 +232,3 @@ def test_pq_adc_batch_matches_ref(rng, n, b, m):
     out = pq_adc_batch(codes, luts, block_n=256)
     ref = pq_adc_batch_ref(codes, luts)
     np.testing.assert_allclose(np.asarray(out), np.asarray(ref), rtol=1e-6)
-
-
-@pytest.mark.parametrize("B,S,H,Hk,dh,causal,bq,bk", [
-    (2, 16, 4, 2, 8, True, 8, 8),
-    (1, 32, 2, 2, 16, False, 16, 8),
-    (2, 64, 6, 3, 8, True, 16, 16),
-    (1, 24, 4, 1, 8, True, 8, 8),       # MQA
-    (1, 16, 2, 2, 8, True, 16, 16),     # single block
-])
-def test_flash_attention_kernel_matches_ref(rng, B, S, H, Hk, dh, causal,
-                                            bq, bk):
-    from repro.kernels.flash_attn import flash_attention, flash_attn_ref
-    q = jnp.asarray(rng.standard_normal((B, S, H, dh)), jnp.float32)
-    k = jnp.asarray(rng.standard_normal((B, S, Hk, dh)), jnp.float32)
-    v = jnp.asarray(rng.standard_normal((B, S, Hk, dh)), jnp.float32)
-    out = flash_attention(q, k, v, causal=causal, block_q=bq, block_k=bk)
-    ref = flash_attn_ref(q, k, v, causal=causal)
-    np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
-                               rtol=2e-5, atol=2e-5)
-
-
-def test_flash_attention_kernel_bf16(rng):
-    from repro.kernels.flash_attn import flash_attention, flash_attn_ref
-    q = jnp.asarray(rng.standard_normal((1, 32, 4, 8)), jnp.bfloat16)
-    k = jnp.asarray(rng.standard_normal((1, 32, 2, 8)), jnp.bfloat16)
-    v = jnp.asarray(rng.standard_normal((1, 32, 2, 8)), jnp.bfloat16)
-    out = flash_attention(q, k, v, block_q=16, block_k=16)
-    ref = flash_attn_ref(q, k, v)
-    np.testing.assert_allclose(np.asarray(out, np.float32),
-                               np.asarray(ref, np.float32),
-                               rtol=5e-2, atol=5e-2)

@@ -1,5 +1,7 @@
 """Navigation graph build + search quality."""
 
+import functools
+
 import jax.numpy as jnp
 import numpy as np
 import pytest
@@ -118,9 +120,9 @@ def _near_points(g, rng, b):
             ).astype(np.float32)
 
 
-def _knn_graph():
+def _knn_graph(dim=24):
     rng = np.random.default_rng(3)
-    pts = clustered_vectors(rng, 600, 24, n_clusters=20)
+    pts = clustered_vectors(rng, 600, dim, n_clusters=20)
     return ng.knn_graph_exact(pts, degree=12), _near_points
 
 
@@ -153,10 +155,11 @@ def _sphere_graph():
     return ng.knn_graph_exact(pts, degree=12), near_centre
 
 
-@pytest.mark.parametrize("make_graph", [_knn_graph, _padded_graph,
-                                        _sphere_graph],
-                         ids=["knn_seeded", "padded_entry_only",
-                              "sphere_near_ties"])
+@pytest.mark.parametrize("make_graph", [
+    _knn_graph, functools.partial(_knn_graph, 96),
+    functools.partial(_knn_graph, 128), _padded_graph, _sphere_graph],
+    ids=["knn_seeded", "knn_seeded_96d", "knn_seeded_128d",
+         "padded_entry_only", "sphere_near_ties"])
 @pytest.mark.parametrize("b", [1, 3, 8])
 def test_search_batch_matches_per_query_search(make_graph, b):
     g, make_queries = make_graph()

@@ -13,11 +13,18 @@ def _data(rng, n=512, d=32):
     return jnp.asarray(rng.standard_normal((n, d)), jnp.float32)
 
 
-def test_encode_shape_dtype(rng):
-    data = _data(rng)
-    cb = pq.train_codebooks(jax.random.key(0), data, m=8, nbits=8, iters=4)
+# (dim, M) of the tier-1 shape and of the two the chip serves: SIFT's
+# 128-d with 32-byte codes, DEEP's 96-d with 24-byte codes
+SHAPES = [(32, 8), (128, 32), (96, 24)]
+SERVED = SHAPES[1:]
+
+
+@pytest.mark.parametrize("d, m", SHAPES)
+def test_encode_shape_dtype(rng, d, m):
+    data = _data(rng, d=d)
+    cb = pq.train_codebooks(jax.random.key(0), data, m=m, nbits=8, iters=4)
     codes = pq.encode(cb, data)
-    assert codes.shape == (512, 8) and codes.dtype == jnp.uint8
+    assert codes.shape == (512, m) and codes.dtype == jnp.uint8
 
 
 def test_adc_equals_exact_on_centroids(rng):
@@ -45,10 +52,11 @@ def test_quantization_error_decreases_with_m(rng):
     assert errs[0] >= errs[1] >= errs[2]
 
 
-def test_adc_preserves_neighbor_ranking(rng):
+@pytest.mark.parametrize("d, m", [(32, 16), *SERVED])
+def test_adc_preserves_neighbor_ranking(rng, d, m):
     """PQ distances must correlate with exact distances (rank quality)."""
-    data = _data(rng, 512, 32)
-    cb = pq.train_codebooks(jax.random.key(0), data, m=16, iters=8)
+    data = _data(rng, 512, d)
+    cb = pq.train_codebooks(jax.random.key(0), data, m=m, iters=8)
     codes = pq.encode(cb, data)
     q = np.asarray(data[0])
     lut = pq.adc_lut(cb, jnp.asarray(q))
@@ -66,6 +74,17 @@ def test_adc_preserves_neighbor_ranking(rng):
 def test_lut_is_subspace_distance(m, d_mult, seed):
     """Property: LUT[i, j] == squared L2 between query sub-vector i and
     centroid j of sub-space i."""
+    _check_lut(m, d_mult, seed)
+
+
+@pytest.mark.parametrize("d, m", SERVED)
+@settings(max_examples=4, deadline=None)
+@given(seed=st.integers(0, 2 ** 16))
+def test_lut_is_subspace_distance_at_served_shapes(d, m, seed):
+    _check_lut(m, d // m, seed)
+
+
+def _check_lut(m, d_mult, seed):
     d = m * d_mult
     rng = np.random.default_rng(seed)
     data = jnp.asarray(rng.standard_normal((64, d)), jnp.float32)

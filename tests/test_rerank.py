@@ -199,6 +199,8 @@ IO_FIELDS = ("ios", "pages_requested", "buffer_hits", "intra_merged",
     dict(buffer_pages=3, intra_merge=False, batch_size=8),
     dict(k=1, batch_size=8),
     dict(k=40),                            # more than some queries score
+    dict(d=96),                            # the served vector widths
+    dict(d=128),
 ], ids=lambda c: "-".join(f"{k}={v}" for k, v in c.items()) or "default")
 def test_window_form_matches_per_query_reference(case):
     """A block of queries against the frozen per-query form, row by row:
@@ -208,8 +210,9 @@ def test_window_form_matches_per_query_reference(case):
     ssd_kw = {key: case.pop(key) for key in ("intra_merge", "use_buffer",
                                              "buffer_pages") if key in case}
     k = case.pop("k", 10)
+    d = case.pop("d", 16)
     rng = np.random.default_rng(11)
-    n, d = 400, 16
+    n = 400
     data = rng.standard_normal((n, d)).astype(np.float32)
     lay = StorageLayout.build(rng.integers(0, 12, n).astype(np.int64), 12,
                               4 * d)

@@ -112,8 +112,7 @@ def test_build_assignment_compiles(one_chip, fn):
 
 
 def _mesh_ctx(mesh):
-    from repro.models.layers import ShardCtx
-    from repro.sharding.spec import rules_for_mesh
+    from repro.sharding import ShardCtx, rules_for_mesh
     return ShardCtx(mesh=mesh, rules=rules_for_mesh(mesh))
 
 
